@@ -28,6 +28,10 @@ gate and writes ``BENCH_async_smoke.json`` (CI harness + check_regression
 input):
 
     PYTHONPATH=src python -m benchmarks.async_bench [--smoke]
+
+This is a CPU harness, not a chip measurement: its children run on emulated
+host devices with ``JAX_PLATFORMS=cpu`` pinned (``common.spawn_cpu_child``),
+and every time it records is a CPU time.
 """
 
 from __future__ import annotations
@@ -35,9 +39,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
-import subprocess
-import sys
 import time
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_async.json")
@@ -149,24 +150,9 @@ def _child(w: dict) -> dict:
 
 
 def _spawn(w: dict) -> dict:
-    env = dict(os.environ)
-    flags = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "",
-        env.get("XLA_FLAGS", ""),
-    ).strip()
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={w['devices']} " + flags
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.async_bench", "--child",
-         json.dumps(w)],
-        env=env, capture_output=True, text=True, timeout=1200,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"async_bench child failed:\n{proc.stdout}\n{proc.stderr}"
-        )
-    return json.loads(proc.stdout.splitlines()[-1])
+    from benchmarks import common
+
+    return common.spawn_cpu_child("benchmarks.async_bench", w, w["devices"])
 
 
 def main(argv=None):

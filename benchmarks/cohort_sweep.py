@@ -39,6 +39,10 @@ feddyn) reaches target in fewer rounds than plain fedavg under DPP
 selection.  Writes ``BENCH_algo.json`` / ``BENCH_algo_smoke.json``:
 
     PYTHONPATH=src python -m benchmarks.cohort_sweep --algos [--smoke]
+
+This is a CPU harness, not a chip measurement: its children run on emulated
+host devices with ``JAX_PLATFORMS=cpu`` pinned (``common.spawn_cpu_child``),
+and every time it records is a CPU time.
 """
 
 from __future__ import annotations
@@ -46,9 +50,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
-import subprocess
-import sys
 import time
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_cohort.json")
@@ -382,24 +383,12 @@ def _algo_child_run(w: dict, n_shards: int) -> dict:
 
 
 def _spawn(w: dict, n_shards: int, algos: bool = False) -> dict:
-    env = dict(os.environ)
-    flags = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "",
-        env.get("XLA_FLAGS", ""),
-    ).strip()
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n_shards} " + flags
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.cohort_sweep", "--child",
-         json.dumps(dict(workload=w, n_shards=n_shards, algos=algos))],
-        env=env, capture_output=True, text=True, timeout=1800,
+    from benchmarks import common
+
+    return common.spawn_cpu_child(
+        "benchmarks.cohort_sweep",
+        dict(workload=w, n_shards=n_shards, algos=algos), n_shards, timeout=1800,
     )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"cohort_sweep child failed:\n{proc.stdout}\n{proc.stderr}"
-        )
-    return json.loads(proc.stdout.splitlines()[-1])
 
 
 def _main_algos(smoke: bool):

@@ -13,6 +13,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
+import subprocess
+import sys
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -44,6 +47,35 @@ def scale() -> paper_cnn.PaperExperiment:
             cnn_channels=(8, 16), fc1_dim=64,
         )
     return paper_cnn.bench_scale()
+
+
+def spawn_cpu_child(module: str, payload, devices: int,
+                    timeout: int = 1200) -> Dict:
+    """Run ``python -m <module> --child <payload>`` on ``devices`` emulated
+    CPU devices and return the JSON object its last stdout line prints.
+
+    The child is a CPU harness, never a chip measurement: it pins
+    ``JAX_PLATFORMS=cpu`` beside the forced host device count, so it never
+    reaches for an accelerator that its parent process may hold."""
+    env = dict(os.environ)
+    flags = re.sub(
+        r"--xla_force_host_platform_device_count=\d+", "",
+        env.get("XLA_FLAGS", ""),
+    ).strip()
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={devices} " + flags
+    ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "-m", module, "--child", json.dumps(payload)],
+        env=env, capture_output=True, text=True, timeout=timeout,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"{module} child (devices={devices}) failed:\n"
+            f"{proc.stdout}\n{proc.stderr}"
+        )
+    return json.loads(proc.stdout.splitlines()[-1])
 
 
 def _load_grid() -> Dict:

@@ -38,6 +38,10 @@ convergence gate and writes ``BENCH_fault_smoke.json`` (CI harness +
 check_regression input):
 
     PYTHONPATH=src python -m benchmarks.fault_bench [--smoke]
+
+This is a CPU harness, not a chip measurement: its children run on emulated
+host devices with ``JAX_PLATFORMS=cpu`` pinned (``common.spawn_cpu_child``),
+and every time it records is a CPU time.
 """
 
 from __future__ import annotations
@@ -45,9 +49,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
-import subprocess
-import sys
 import time
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_fault.json")
@@ -198,24 +199,9 @@ def _child(w: dict) -> dict:
 
 
 def _spawn(w: dict) -> dict:
-    env = dict(os.environ)
-    flags = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "",
-        env.get("XLA_FLAGS", ""),
-    ).strip()
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={w['devices']} " + flags
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.fault_bench", "--child",
-         json.dumps(w)],
-        env=env, capture_output=True, text=True, timeout=1200,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fault_bench child failed:\n{proc.stdout}\n{proc.stderr}"
-        )
-    return json.loads(proc.stdout.splitlines()[-1])
+    from benchmarks import common
+
+    return common.spawn_cpu_child("benchmarks.fault_bench", w, w["devices"])
 
 
 def main(argv=None):

@@ -33,6 +33,10 @@ a small-k slot case) with no perf gates and writes a separate
 ``BENCH_shard_smoke.json`` (CI harness + regression-check input):
 
     PYTHONPATH=src python -m benchmarks.shard_bench [--smoke]
+
+This is a CPU harness, not a chip measurement: its children run on emulated
+host devices with ``JAX_PLATFORMS=cpu`` pinned (``common.spawn_cpu_child``),
+and every time it records is a CPU time.
 """
 
 from __future__ import annotations
@@ -40,9 +44,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
-import subprocess
-import sys
 import time
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_shard.json")
@@ -235,24 +236,9 @@ def _slot_child(devices: int, w: dict) -> dict:
 
 
 def _spawn_payload(devices: int, payload: dict) -> dict:
-    env = dict(os.environ)
-    flags = re.sub(
-        r"--xla_force_host_platform_device_count=\d+", "",
-        env.get("XLA_FLAGS", ""),
-    ).strip()
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices} " + flags
-    ).strip()
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.shard_bench", "--child",
-         json.dumps(payload)],
-        env=env, capture_output=True, text=True, timeout=1200,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"child (devices={devices}) failed:\n{proc.stdout}\n{proc.stderr}"
-        )
-    return json.loads(proc.stdout.splitlines()[-1])
+    from benchmarks import common
+
+    return common.spawn_cpu_child("benchmarks.shard_bench", payload, devices)
 
 
 def _spawn(devices: int, w: dict, check_parity: bool) -> dict:

@@ -47,21 +47,14 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # newer jax graduates shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _checked_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions (the
-    ``check_rep`` kwarg is renamed/retired after 0.4.x)."""
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with varying-manual-axes checking off: the slot and
+    stale bodies return psum'd values whose replication the checker cannot
+    infer statically."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 from repro import checkpoint as checkpoint_lib
 from repro.core import dpp as dpp_lib
@@ -414,8 +407,8 @@ def batch_indices_from_keys(cfg: FLConfig, keys, n_c: int):
     replacement draws, or the (M, n_c) epoch permutation.  Split from
     :func:`batches_from_indices` so the mesh-sharded round can generate every
     plan at the jit level (replicated, tiny int arrays) and keep only the
-    data slicing inside its ``shard_map`` — random-bit generation fused into
-    the shard body miscompiles on jax 0.4.37 (wrong clients' draws).
+    data slicing inside its ``shard_map``: every layout then derives a
+    client's batches from the same key, outside any per-shard program.
     """
     if cfg.local_batch_size is None:
         return None
@@ -720,8 +713,7 @@ def make_round_fn(
         bounded-staleness (:func:`_stale_sharded_body`) resident-layout
         bodies — the cross-path bit-identical-batches parity contract lives
         here, and only data slicing / SGD scans / the psum go inside the
-        shard_map (fusing random-bit generation into the shard body
-        miscompiles on jax 0.4.37: clients read other slots' draws)."""
+        shard_map, so no random bits are drawn per shard."""
         c = state.losses.shape[0]
         n_c = state.client_xs.shape[1]
         slot_full = jnp.argmax(sel[None, :] == jnp.arange(c)[:, None], axis=1)

@@ -6,9 +6,12 @@ per tile ((bq×hd)·(hd×bk) and (bq×bk)·(bk×hd)) land on the MXU, and the
 softmax running stats (m, l) plus the (bq×hd) accumulator persist in VMEM
 scratch across the sequential innermost KV grid dimension.
 
-Grid: (B, H, S/bq, S/bk) — the KV dim is innermost/sequential.  GQA is
-handled in the BlockSpec index maps: query head ``h`` reads KV head
-``h // (H / Hk)`` — no repeated-KV materialisation in HBM.
+Grid: (B, H, S/bq, S/bk) — the KV dim is innermost/sequential.  The kernel
+runs on a head-major layout, (B, H, S, hd), so every block's last two dims
+are (tile, hd) as the TPU's (8, 128) block rule wants; the public
+(B, S, H, hd) arrays are transposed on the way in and out.  GQA is handled
+in the BlockSpec index maps: query head ``h`` reads KV head ``h // (H / Hk)``
+— no repeated-KV materialisation in HBM.
 
 Causality + optional sliding window are applied as in-tile masks; KV tiles
 entirely above the diagonal (or entirely outside the window) write nothing
@@ -22,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel"]
 
@@ -49,9 +53,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, window,
 
     @pl.when(relevant)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, hd)
+        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, hd)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[0, 0].astype(jnp.float32)  # (bk, hd)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
@@ -63,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, window,
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]  # (bq, 1)
-        m_cur = jnp.maximum(m_prev[:, 0], jnp.max(s, axis=1))[:, None]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_cur)  # (bq, bk)
         alpha = jnp.exp(m_prev - m_cur)  # (bq, 1)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -76,7 +80,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, window,
     def _finalize():
         l = l_scr[...]
         o = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
+        o_ref[0, 0] = o.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -100,11 +104,9 @@ def flash_attention_kernel(
     bq = min(block_q, s)
     bk = min(block_k, s)
     sp = -(-s // max(bq, bk)) * max(bq, bk)
-    if sp != s:
-        pad = sp - s
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    pad = ((0, 0), (0, 0), (0, sp - s), (0, 0))
+    # head-major (B, H, S, hd): blocks end in (tile, hd)
+    q, k, v = (jnp.pad(x.transpose(0, 2, 1, 3), pad) for x in (q, k, v))
     n_q, n_kv = sp // bq, sp // bk
     grid = (b, h, n_q, n_kv)
 
@@ -120,19 +122,14 @@ def flash_attention_kernel(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sp, h, hd), q.dtype),
-        scratch_shapes=[_vmem((bq, 1)), _vmem((bq, 1)), _vmem((bq, hd))],
+        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sp, hd), q.dtype),
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                        for shape in ((bq, 1), (bq, 1), (bq, hd))],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :s]
-
-
-def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, jnp.float32)
+    return out[:, :, :s].transpose(0, 2, 1, 3)

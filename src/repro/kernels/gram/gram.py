@@ -16,16 +16,23 @@ MXU (``preferred_element_type=float32`` — bf16 inputs accumulate in fp32):
 
 Grid: (N/bm, N/bn, M/bk), K innermost (sequential on TPU).  The default
 (128, 128, 128) tiles keep the working set ≈ 0.2 MB ≪ VMEM and all matmul
-dims 128-aligned.
+dims 128-aligned.  The normalise-and-Gram kernel pads the client axis up to
+its tiles instead of clamping a tile to C (a (128, 100) block at the
+paper's C = 100 breaks the TPU's (8, 128) block rule) and reads the two
+min-max scalars from SMEM.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import mxu_precision
 
 __all__ = ["gram_kernel", "normalized_gram_kernel"]
 
@@ -41,7 +48,7 @@ def _gram_body(a_ref, b_ref, out_ref):
 
     out_ref[...] += jax.lax.dot_general(
         a_ref[...], b_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=mxu_precision(a_ref.dtype),
     )
 
 
@@ -75,15 +82,15 @@ def gram_kernel(
     return out[:n, :n]
 
 
-def _norm_gram_body(a_ref, b_ref, lo_ref, rng_ref, out_ref, *, c, bk, compute_dtype):
+def _norm_gram_body(a_ref, b_ref, stats_ref, out_ref, *, c, bk, compute_dtype):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lo = lo_ref[0, 0]
-    rng = rng_ref[0, 0]
+    lo = stats_ref[0, 0]
+    rng = stats_ref[0, 1]
     # eq.-(14) epilogue fused into the contraction prologue: similarity
     # S = 1 − (S0 − lo)/rng; pad rows (the reduction dim) masked to 0 so the
     # garbage region of the padded S0 never reaches the accumulator.
@@ -93,7 +100,7 @@ def _norm_gram_body(a_ref, b_ref, lo_ref, rng_ref, out_ref, *, c, bk, compute_dt
     out_ref[...] += jax.lax.dot_general(
         sa.astype(compute_dtype), sb.astype(compute_dtype),
         (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=mxu_precision(compute_dtype),
     )
 
 
@@ -119,22 +126,20 @@ def normalized_gram_kernel(
     bf16 profiles — accumulation stays fp32).
     """
     p = s0.shape[0]
-    bm, bn, bk = min(block_m, c), min(block_n, c), min(block_k, p)
-    pp = max(_pad_up(p, bm), _pad_up(_pad_up(p, bm), bn), _pad_up(p, bk))
+    bm, bn, bk = block_m, block_n, block_k
+    pp = _pad_up(p, math.lcm(bm, bn, bk))
     s0p = jnp.pad(s0, ((0, pp - p), (0, pp - p)))
-    lo2 = jnp.asarray(lo, jnp.float32).reshape(1, 1)
-    rng2 = jnp.asarray(rng, jnp.float32).reshape(1, 1)
+    stats = jnp.stack([lo, rng]).astype(jnp.float32).reshape(1, 2)
     out = pl.pallas_call(
         functools.partial(_norm_gram_body, c=c, bk=bk, compute_dtype=compute_dtype),
         grid=(pp // bm, pp // bn, pp // bk),
         in_specs=[
             pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (lo, rng)
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pp, pp), jnp.float32),
         interpret=interpret,
-    )(s0p, s0p, lo2, rng2)
+    )(s0p, s0p, stats)
     return out[:c, :c]

@@ -18,21 +18,43 @@ Two entry points:
   zero diagonal).
 * :func:`pairwise_dists_stats_kernel` — the fused eq.-(14) front end: the
   last K iteration runs a **sqrt epilogue** in-tile (clamp → pin diagonal →
-  ``√``) and reduces each tile's masked min/max into (grid_m, grid_n) stats
-  outputs, so the min-max normalisation scalars cost one tiny reduction
-  instead of a second O(C²) pass.  Feeds the ``gram`` kernel
+  ``√``) and reduces each tile's masked min/max into one (8, 128) stats tile
+  per output tile (the scalar broadcast over a full vreg: Mosaic stores no
+  scalars to VMEM), so the min-max normalisation scalars cost one tiny
+  reduction instead of a second O(C²) pass.  Feeds the ``gram`` kernel
   (``repro.kernels.gram``), making profiles → DPP kernel two launches.
+
+C is padded up to a multiple of the row/column tiles, never clamped to a
+tile of C rows: a C-wide block (e.g. C = 100) breaks the TPU's (8, 128)
+block rule once a second kernel tiles the padded result.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu_precision
+
 __all__ = ["pairwise_dists_stats_kernel", "pairwise_sq_dists_kernel"]
+
+# one f32 vreg: the smallest tile a per-tile statistic can be stored as
+_STAT_TILE = (8, 128)
+
+
+def _padded_operands(f, block_m, block_n, block_k):
+    """Tile sizes and the zero-padded profiles for a (C, Q) matrix: C up to a
+    common multiple of the row/column tiles, Q up to the K tile (or Q itself
+    when it is the smaller)."""
+    c, q = f.shape
+    bk = min(block_k, q)
+    cp = -(-c // math.lcm(block_m, block_n)) * math.lcm(block_m, block_n)
+    qp = -(-q // bk) * bk
+    return bk, cp, jnp.pad(f, ((0, cp - c), (0, qp - q)))
 
 
 def _kernel(a_ref, b_ref, out_ref):
@@ -47,7 +69,8 @@ def _kernel(a_ref, b_ref, out_ref):
     a2 = jnp.sum(a * a, axis=1, keepdims=True)  # (bm, 1)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)  # (bn, 1)
     ab = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=mxu_precision(a_ref.dtype),
     )  # (bm, bn) on the MXU
     out_ref[...] += a2 + b2.T - 2.0 * ab
 
@@ -63,15 +86,10 @@ def pairwise_sq_dists_kernel(
     interpret: bool = False,
 ) -> jax.Array:
     """F (C, Q) -> D2 (C, C); pads C and Q up to tile multiples internally."""
-    c, q = f.shape
-    bm, bn, bk = min(block_m, c), min(block_n, c), min(block_k, q)
-    cp = -(-c // bm) * bm
-    cpn = -(-cp // bn) * bn  # common padded C for both tilings
-    cp = max(cp, cpn)
-    qp = -(-q // bk) * bk
-    fp = jnp.pad(f, ((0, cp - c), (0, qp - q)))
-
-    grid = (cp // bm, cp // bn, qp // bk)
+    c = f.shape[0]
+    bm, bn = block_m, block_n
+    bk, cp, fp = _padded_operands(f, bm, bn, block_k)
+    grid = (cp // bm, cp // bn, fp.shape[1] // bk)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -101,7 +119,8 @@ def _stats_kernel(a_ref, b_ref, out_ref, mn_ref, mx_ref, *, c, bm, bn):
     a2 = jnp.sum(a * a, axis=1, keepdims=True)  # (bm, 1)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)  # (bn, 1)
     ab = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=mxu_precision(a_ref.dtype),
     )  # (bm, bn) on the MXU
     out_ref[...] += a2 + b2.T - 2.0 * ab
 
@@ -115,10 +134,13 @@ def _stats_kernel(a_ref, b_ref, out_ref, mn_ref, mx_ref, *, c, bm, bn):
         s0 = jnp.sqrt(jnp.where(rows == cols, 0.0, d2))
         out_ref[...] = s0
         # masked per-tile min/max (pad region excluded) for the eq.-(14)
-        # min-max normalisation — reduced to scalars by the caller
+        # min-max normalisation, broadcast over the tile's stats vreg and
+        # reduced to scalars by the caller
         valid = (rows < c) & (cols < c)
-        mn_ref[0, 0] = jnp.min(jnp.where(valid, s0, jnp.inf))
-        mx_ref[0, 0] = jnp.max(jnp.where(valid, s0, -jnp.inf))
+        lo = jnp.min(jnp.where(valid, s0, jnp.inf), keepdims=True)
+        hi = jnp.max(jnp.where(valid, s0, -jnp.inf), keepdims=True)
+        mn_ref[...] = jnp.broadcast_to(lo, mn_ref.shape)
+        mx_ref[...] = jnp.broadcast_to(hi, mx_ref.shape)
 
 
 @functools.partial(
@@ -138,15 +160,11 @@ def pairwise_dists_stats_kernel(
     min/max over the real (C, C) region, fp monotonicity making them equal
     to the reference's post-sqrt extrema.
     """
-    c, q = f.shape
-    bm, bn, bk = min(block_m, c), min(block_n, c), min(block_k, q)
-    cp = -(-c // bm) * bm
-    cpn = -(-cp // bn) * bn  # common padded C for both tilings
-    cp = max(cp, cpn)
-    qp = -(-q // bk) * bk
-    fp = jnp.pad(f, ((0, cp - c), (0, qp - q)))
-
-    grid = (cp // bm, cp // bn, qp // bk)
+    c = f.shape[0]
+    bm, bn = block_m, block_n
+    bk, cp, fp = _padded_operands(f, bm, bn, block_k)
+    grid = (cp // bm, cp // bn, fp.shape[1] // bk)
+    stats_shape = (grid[0] * _STAT_TILE[0], grid[1] * _STAT_TILE[1])
     s0, mn, mx = pl.pallas_call(
         functools.partial(_stats_kernel, c=c, bm=bm, bn=bn),
         grid=grid,
@@ -156,13 +174,13 @@ def pairwise_dists_stats_kernel(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec(_STAT_TILE, lambda i, j, k: (i, j)),
+            pl.BlockSpec(_STAT_TILE, lambda i, j, k: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((cp, cp), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32),
+            jax.ShapeDtypeStruct(stats_shape, jnp.float32),
+            jax.ShapeDtypeStruct(stats_shape, jnp.float32),
         ],
         interpret=interpret,
     )(fp, fp)

@@ -14,9 +14,12 @@ Recurrence (per head):
     y_t = r_tᵀ (S + diag(u ⊙ k_t) v_tᵀ)
     S  ← diag(w_t) S + k_t v_tᵀ
 
-Inputs r/k/v/w are (B, T, H, hd); the initial state (B, H, hd, hd) streams in
-once at chunk 0 and the final state streams out at the last chunk (decode
-hand-off).
+Inputs r/k/v/w are (B, T, H, hd); the kernel reads them head-major,
+(B, H, T, hd), so every block ends in (block_t, hd) as the TPU's (8, 128)
+block rule wants.  Each chunk is upcast once into f32 VMEM scratch, where a
+token's row can be loaded at any offset (bf16 rows are packed in pairs and
+cannot).  The initial state (B, H, hd, hd) streams in once at chunk 0 and
+the final state streams out at the last chunk (decode hand-off).
 """
 
 from __future__ import annotations
@@ -32,27 +35,27 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["wkv6_kernel"]
 
 
-def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref, s_scr, *, bt, n_t):
+def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
+            s_scr, r_scr, k_scr, v_scr, w_scr, y_scr, *, bt, n_t):
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _load_state():
         s_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)  # (hd,)
+    for src, dst in ((r_ref, r_scr), (k_ref, k_scr), (v_ref, v_scr), (w_ref, w_scr)):
+        dst[...] = src[0, 0].astype(jnp.float32)
+    u = jnp.transpose(u_ref[0].astype(jnp.float32))  # (hd, 1)
 
     def step(t, s):
-        r = r_ref[0, t, 0, :].astype(jnp.float32)  # (hd,)
-        k = k_ref[0, t, 0, :].astype(jnp.float32)
-        v = v_ref[0, t, 0, :].astype(jnp.float32)
-        w = w_ref[0, t, 0, :].astype(jnp.float32)
-        kv = k[:, None] * v[None, :]  # (hd_k, hd_v) rank-1
-        y = jnp.sum((s + u[:, None] * kv) * r[:, None], axis=0)  # (hd_v,)
-        y_ref[0, t, 0, :] = y.astype(y_ref.dtype)
-        return w[:, None] * s + kv
+        row = pl.ds(t, 1)
+        r, k, v, w = r_scr[row, :], k_scr[row, :], v_scr[row, :], w_scr[row, :]
+        kv = jnp.transpose(k) * v  # (hd_k, hd_v) rank-1
+        y_scr[row, :] = jnp.sum((s + u * kv) * jnp.transpose(r), axis=0, keepdims=True)
+        return jnp.transpose(w) * s + kv
 
-    s = lax.fori_loop(0, bt, step, s_scr[...])
-    s_scr[...] = s
+    s_scr[...] = lax.fori_loop(0, bt, step, s_scr[...])
+    y_ref[0, 0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ti == n_t - 1)
     def _store_state():
@@ -74,33 +77,33 @@ def wkv6_kernel(
     b, t, h, hd = r.shape
     bt = min(block_t, t)
     tp = -(-t // bt) * bt
-    if tp != t:
-        pad = ((0, 0), (0, tp - t), (0, 0), (0, 0))
-        # pad with w=1, k=0 so padded steps leave the state untouched
-        r, k, v = (jnp.pad(x, pad) for x in (r, k, v))
-        w = jnp.pad(w, pad, constant_values=1.0)
+    pad = ((0, 0), (0, 0), (0, tp - t), (0, 0))
+    # head-major (B, H, T, hd); pad with w=1, k=0 so padded steps leave the
+    # state untouched
+    r, k, v = (jnp.pad(x.transpose(0, 2, 1, 3), pad) for x in (r, k, v))
+    w = jnp.pad(w.transpose(0, 2, 1, 3), pad, constant_values=1.0)
     n_t = tp // bt
-    grid = (b, h, n_t)
 
-    seq_spec = pl.BlockSpec((1, bt, 1, hd), lambda bi, hi, ti: (bi, ti, hi, 0))
+    seq_spec = pl.BlockSpec((1, 1, bt, hd), lambda bi, hi, ti: (bi, hi, ti, 0))
     state_spec = pl.BlockSpec((1, 1, hd, hd), lambda bi, hi, ti: (bi, hi, 0, 0))
+    chunk = pltpu.VMEM((bt, hd), jnp.float32)
     y, s_out = pl.pallas_call(
         functools.partial(_kernel, bt=bt, n_t=n_t),
-        grid=grid,
+        grid=(b, h, n_t),
         in_specs=[
             seq_spec,
             seq_spec,
             seq_spec,
             seq_spec,
-            pl.BlockSpec((1, hd), lambda bi, hi, ti: (hi, 0)),
+            pl.BlockSpec((1, 1, hd), lambda bi, hi, ti: (hi, 0, 0)),
             state_spec,
         ],
         out_specs=[seq_spec, state_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, tp, h, hd), r.dtype),
+            jax.ShapeDtypeStruct((b, h, tp, hd), r.dtype),
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)] + [chunk] * 5,
         interpret=interpret,
-    )(r, k, v, w, u, s0)
-    return y[:, :t], s_out
+    )(r, k, v, w, u.reshape(h, 1, hd), s0)
+    return y[:, :, :t].transpose(0, 2, 1, 3), s_out

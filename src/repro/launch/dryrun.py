@@ -5,13 +5,16 @@ compiles, fits, and report its roofline inputs — without real hardware.
     python -m repro.launch.dryrun --sweep --out results/dryrun.jsonl
     python -m repro.launch.dryrun --arch mixtral-8x7b --shape decode_32k --multi-pod
 
-The first two lines below force 512 host platform devices; this module must
-therefore never be imported by tests/benches directly (they spawn it as a
-subprocess) — smoke tests must see 1 device.
+A CPU harness, not a chip measurement: the lines below pin
+``JAX_PLATFORMS=cpu`` and force 512 host platform devices before JAX is
+imported, so the dry-run never claims an attached accelerator.  This module
+must therefore never be imported by tests/benches directly (they spawn it as
+a subprocess) — smoke tests must see 1 device.
 """
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -496,9 +499,7 @@ def _compile_once(spec, cfg, dims, mesh, multi_pod, steps_unroll=1, scan_rounds=
         )
         rules = spec.serve_rules
         donate = (2,)  # caches
-    # jax.set_mesh is >= 0.5; entering the Mesh object is the older spelling
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with mesh_ctx, sh.use_rules(rules, multi_pod):
+    with jax.set_mesh(mesh), sh.use_rules(rules, multi_pod):
         compiled = jax.jit(step, donate_argnums=donate).lower(*args).compile()
     return compiled
 

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.obs import TelemetrySink
 from repro.obs import tracing as obs_tracing_lib
@@ -266,7 +267,9 @@ def main():
     ap.add_argument("--profile-dir", default=None, metavar="PATH",
                     help="capture a jax.profiler trace of the run into PATH "
                          "(TensorBoard-loadable)")
-    serve(ap.parse_args())
+    args = ap.parse_args()
+    use_compile_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

@@ -31,6 +31,7 @@ from repro.fl.faults import AGGREGATORS, FAULT_NAMES
 from repro.fl.local_algos import ALGO_NAMES
 from repro.fl.scenarios import SCENARIO_NAMES
 from repro.fl.staleness import DECAY_FAMILIES
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_client_mesh
 from repro.models import transformer as T
 from repro.obs import TelemetrySink
@@ -354,6 +355,7 @@ def main():
                     help="capture a jax.profiler trace of the run into PATH "
                          "(TensorBoard-loadable)")
     args = ap.parse_args()
+    use_compile_cache()
     (run_fl if args.mode == "fl" else run_pretrain)(args)
 
 

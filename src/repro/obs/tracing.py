@@ -8,8 +8,7 @@ Two context managers:
 * :func:`annotate` — named host spans (``jax.profiler.TraceAnnotation``)
   around the hot boundaries: scan chunks, selection reprofiles, serve
   decode chunks and admissions.  Annotations are cheap enough to apply
-  unconditionally — they only record when a trace is active — and fall
-  back to a no-op on jax builds without ``TraceAnnotation``.
+  unconditionally — they only record when a trace is active.
 """
 
 from __future__ import annotations
@@ -20,8 +19,6 @@ from typing import Iterator, Optional
 import jax
 
 __all__ = ["annotate", "trace"]
-
-_TraceAnnotation = getattr(jax.profiler, "TraceAnnotation", None)
 
 
 @contextlib.contextmanager
@@ -35,7 +32,5 @@ def trace(profile_dir: Optional[str]) -> Iterator[None]:
 
 
 def annotate(name: str):
-    """A named profiler span (no-op context on jax builds without one)."""
-    if _TraceAnnotation is None:  # pragma: no cover - jax-version dependent
-        return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+    """A named profiler span."""
+    return jax.profiler.TraceAnnotation(name)

