@@ -1,0 +1,161 @@
+"""Plain reference of the CNN federation, independent of the program.
+
+Written from the paper's description (§3-§4) in ``jax.numpy``: the
+2-conv/2-FC CNN (5x5 'SAME' convolutions, ReLU, 2x2 max-pools, FC-1, FC-2),
+its cross-entropy loss, a client's E full-batch gradient-descent steps,
+eq.-(6) aggregation weighted by the clients' sample counts, accuracy, the
+eq.-(11) FC-1 profiles and the eq.-(14) kernel L = SᵀS.  The initial
+weights follow the same recipe as the program's (Kaiming-uniform on
+fan-in, zero biases, one key split four ways), so that the reference
+starts where the program starts without taking the program's weights.
+
+Every product runs at the precision the configuration states
+(``matmul_precision``: on the chip, ``"default"`` is one bfloat16 pass of
+the MXU with float32 accumulation), on float32 arrays; the kernel is
+worked out in float64 on the host.  ``dtype=bfloat16`` gives the control:
+the same arithmetic on bfloat16 arrays, and the kernel in float32 at
+``Precision.HIGH`` on the device.  Every pass runs in blocks, so the
+reference fits beside what the run keeps.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+__all__ = ["init_params", "profiles", "eq14_kernel", "replay", "accuracy"]
+
+F32 = jnp.float32
+
+
+def _prec(precision: str):
+    return lax.Precision[precision.upper()]
+
+
+def init_params(key, cfg: dict) -> dict:
+    h, w = cfg["image_hw"]
+    c1, c2 = cfg["channels"]
+    q, k_out = cfg["fc1_dim"], cfg["num_classes"]
+    shapes = [(5, 5, 1, c1), (5, 5, c1, c2), ((h // 4) * (w // 4) * c2, q), (q, k_out)]
+    keys = jax.random.split(key, 4)
+    out = {}
+    for name, k, shape in zip(("conv1", "conv2", "fc1", "fc2"), keys, shapes):
+        fan_in = shape[0] * shape[1] * shape[2] if len(shape) == 4 else shape[0]
+        bound = jnp.sqrt(6.0 / fan_in)
+        out[name] = {"w": jax.random.uniform(k, shape, F32, -bound, bound),
+                     "b": jnp.zeros((shape[-1],), F32)}
+    return out
+
+
+def _forward(params, x, dtype, precision):
+    """(logits, FC-1 pre-activations) of a batch x (B, H, W, 1)."""
+    p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
+    prec = _prec(precision)
+
+    def conv(h, layer):
+        y = lax.conv_general_dilated(h, layer["w"], (1, 1), "SAME",
+                                     dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                                     precision=prec)
+        return y + layer["b"]
+
+    def pool(h):
+        return lax.reduce_window(h, -jnp.inf, lax.max,
+                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+    h = pool(jax.nn.relu(conv(x.astype(dtype), p["conv1"])))
+    h = pool(jax.nn.relu(conv(h, p["conv2"])))
+    h = h.reshape(h.shape[0], -1)
+    fc1 = jnp.dot(h, p["fc1"]["w"], precision=prec) + p["fc1"]["b"]
+    logits = jnp.dot(jax.nn.relu(fc1), p["fc2"]["w"], precision=prec) + p["fc2"]["b"]
+    return logits, fc1
+
+
+def _loss(params, x, y, dtype, precision):
+    logits, _ = _forward(params, x, dtype, precision)
+    logp = jax.nn.log_softmax(logits)
+    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
+def profiles(params, client_xs, dtype=F32, precision="default", block=50):
+    """(C, F) eq.-(11) profiles: each client's mean FC-1 pre-activation."""
+    def one(x):
+        return _forward(params, x, dtype, precision)[1].astype(F32).mean(0).astype(dtype)
+
+    return lax.map(one, client_xs, batch_size=block)
+
+
+def eq14_kernel(f, dtype=F32) -> np.ndarray:
+    """Eq. (14) and L = SᵀS.  float32 profiles: in float64 on the host;
+    the control's: in float32 at ``Precision.HIGH`` on the device."""
+    if dtype == F32:
+        f = np.asarray(f, np.float64)
+        f = f - f.mean(0)  # distances are translation-invariant
+        sq = (f * f).sum(1)
+        d2 = np.maximum(sq[:, None] + sq[None, :] - 2.0 * (f @ f.T), 0.0)
+        np.fill_diagonal(d2, 0.0)
+        s0 = np.sqrt(d2)
+        s = 1.0 - (s0 - s0.min()) / (s0.max() - s0.min())
+        return s.T @ s
+    return np.asarray(_eq14_high(jnp.asarray(f, F32)), np.float64)
+
+
+@jax.jit
+def _eq14_high(f):
+    hi = lax.Precision.HIGH
+    f = f - f.mean(0)
+    sq = (f * f).sum(1)
+    d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * jnp.dot(f, f.T, precision=hi), 0.0)
+    s0 = jnp.sqrt(d2 * (1.0 - jnp.eye(f.shape[0], dtype=F32)))
+    s = 1.0 - (s0 - s0.min()) / (s0.max() - s0.min())
+    return jnp.dot(s.T, s, precision=hi)
+
+
+@functools.partial(jax.jit, static_argnames=("lr", "steps", "dtype", "precision", "keep"))
+def replay(params0, client_xs, client_ys, cohorts, lr, steps, dtype=F32, precision="default",
+           keep=1.0):
+    """Rounds with the given cohorts (R, k): each client takes ``steps``
+    full-batch GD steps from the global params on the first ``keep`` share
+    of its samples, then the eq.-(6) mean weighted by sample counts.
+    -> (params after the R rounds, per-round mean local loss (R,))."""
+    grad = jax.value_and_grad(lambda p, x, y: _loss(p, x, y, dtype, precision))
+
+    def client(p, x, y):
+        n = int(round(x.shape[0] * keep))
+        x, y = x[:n], y[:n]
+
+        def step(p, _):
+            loss, g = grad(p, x, y)
+            p = jax.tree_util.tree_map(lambda a, b: (a - lr * b.astype(a.dtype)).astype(a.dtype), p, g)
+            return p, loss
+
+        return lax.scan(step, p, None, length=steps)
+
+    def one_round(p, cohort):
+        xs, ys = client_xs[cohort], client_ys[cohort]
+        new, losses = jax.vmap(client, in_axes=(None, 0, 0))(p, xs, ys)
+        w = jnp.full((cohort.shape[0],), float(client_xs.shape[1]), F32)
+        w = w / w.sum()
+        agg = jax.tree_util.tree_map(
+            lambda a, o: jnp.tensordot(w.astype(a.dtype), a, axes=1).astype(o.dtype),
+            new, p)
+        return agg, losses.astype(F32).mean()
+
+    p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params0)
+    return lax.scan(one_round, p, cohorts)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
+def accuracy(params, xs, ys, dtype=F32, precision="default", block=4096):
+    """Share of ``xs`` whose largest logit is the label."""
+    n = xs.shape[0]
+    pad = (-n) % block
+    xb = jnp.pad(xs, ((0, pad),) + ((0, 0),) * (xs.ndim - 1)).reshape(-1, block, *xs.shape[1:])
+    yb = jnp.pad(ys, (0, pad), constant_values=-1).reshape(-1, block)
+    hits = lax.map(lambda a: jnp.sum(jnp.argmax(_forward(params, a[0], dtype, precision)[0], -1) == a[1]),
+                   (xb, yb))
+    return hits.sum() / n
