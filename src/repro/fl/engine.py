@@ -479,6 +479,12 @@ def make_client_batches(cfg: FLConfig, key, client_xs, client_ys, sel):
 _ENV_SALT = 0x5CE7A210
 
 
+def _gemd_from_parts(num, den, global_dist):
+    """GEMD (eq. 15) from the cohort's psum'd label-mix partials."""
+    with jax.named_scope("fl.gemd"):
+        return jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+
+
 def make_round_fn(
     cfg: FLConfig,
     loss_fn: Callable,  # loss_fn(params, x, y) -> scalar
@@ -641,16 +647,21 @@ def make_round_fn(
 
     def _single_device_body(state, k_batch, sel, draws=None):
         """Cohort gather + vmapped/mapped local updates on one device."""
-        batches = make_client_batches(cfg, k_batch, state.client_xs, state.client_ys, sel)
-        weights = jnp.take(state.client_sizes, sel)
+        with jax.named_scope("fl.batches"):
+            batches = make_client_batches(
+                cfg, k_batch, state.client_xs, state.client_ys, sel
+            )
+            weights = jnp.take(state.client_sizes, sel)
         round_step = rounds_lib.build_client_parallel_round(
             batched_loss, cfg.lr, steps_of(state), grad_clip=cfg.grad_clip,
             sequential_clients=sequential_clients, update_transform=guard,
             algo=algo,
         )
-        g = metrics_lib.gemd(
-            state.client_label_dists, state.client_sizes, sel, state.global_label_dist
-        )
+        with jax.named_scope("fl.gemd"):
+            g = metrics_lib.gemd(
+                state.client_label_dists, state.client_sizes, sel,
+                state.global_label_dist,
+            )
         state_kw = {}
         if stateful:
             state_kw["client_states"] = jax.tree_util.tree_map(
@@ -668,10 +679,12 @@ def make_round_fn(
                 params, mean_loss = res
                 algo_state = None
             # refresh last-known losses for the selected clients
-            sel_losses = loss_of(
-                params, jnp.take(state.client_xs, sel, 0), jnp.take(state.client_ys, sel, 0)
-            )
-            losses = state.losses.at[sel].set(sel_losses)
+            with jax.named_scope("fl.loss_refresh"):
+                sel_losses = loss_of(
+                    params, jnp.take(state.client_xs, sel, 0),
+                    jnp.take(state.client_ys, sel, 0),
+                )
+                losses = state.losses.at[sel].set(sel_losses)
             out = (params, mean_loss, losses, g)
             return out + (algo_state,) if stateful else out
         # fault masks gathered to the cohort layout (draws are (C,) rows)
@@ -692,11 +705,13 @@ def make_round_fn(
         # refresh only trusted participants, and only when the round's
         # aggregate will actually be kept (survivors floor)
         refresh = delivered & ~flagged & (survivors >= cfg.min_survivors)
-        sel_losses = loss_of(
-            params, jnp.take(state.client_xs, sel, 0), jnp.take(state.client_ys, sel, 0)
-        )
-        keep = jnp.take(state.losses, sel)
-        losses = state.losses.at[sel].set(jnp.where(refresh, sel_losses, keep))
+        with jax.named_scope("fl.loss_refresh"):
+            sel_losses = loss_of(
+                params, jnp.take(state.client_xs, sel, 0),
+                jnp.take(state.client_ys, sel, 0),
+            )
+            keep = jnp.take(state.losses, sel)
+            losses = state.losses.at[sel].set(jnp.where(refresh, sel_losses, keep))
         out = (params, mean_loss, losses, g, flagged_c, survivors)
         if stateful:
             algo_state = _algo_writeback(
@@ -716,10 +731,11 @@ def make_round_fn(
         shard_map, so no random bits are drawn per shard."""
         c = state.losses.shape[0]
         n_c = state.client_xs.shape[1]
-        slot_full = jnp.argmax(sel[None, :] == jnp.arange(c)[:, None], axis=1)
-        key_data = jax.random.key_data(jax.random.split(k_batch, k))
-        client_keys = jax.random.wrap_key_data(key_data[slot_full])
-        return batch_indices_from_keys(cfg, client_keys, n_c)  # (C, ...) | None
+        with jax.named_scope("fl.batches"):
+            slot_full = jnp.argmax(sel[None, :] == jnp.arange(c)[:, None], axis=1)
+            key_data = jax.random.key_data(jax.random.split(k_batch, k))
+            client_keys = jax.random.wrap_key_data(key_data[slot_full])
+            return batch_indices_from_keys(cfg, client_keys, n_c)  # (C, ...) | None
 
     def _sharded_body(state, k_batch, sel, draws=None):
         """shard_map core: in-place masked local updates + psum'd FedAvg.
@@ -755,14 +771,16 @@ def make_round_fn(
             c_loc = local_xs.shape[0]
             gids = lax.axis_index(client_axis) * c_loc + jnp.arange(c_loc)
             mask = jnp.any(sel[None, :] == gids[:, None], axis=1)
-            batches = batches_from_indices(
-                cfg, local_ids[0] if local_ids else None, local_xs, local_ys
-            )
+            with jax.named_scope("fl.batches"):
+                batches = batches_from_indices(
+                    cfg, local_ids[0] if local_ids else None, local_xs, local_ys
+                )
             weights = local_sizes * mask
             # GEMD (eq. 15) partials ride the round's single psum: the cohort
             # label-mix numerator/denominator over this shard's residents
-            w = weights.astype(jnp.float32)
-            gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
+            with jax.named_scope("fl.gemd"):
+                w = weights.astype(jnp.float32)
+                gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
             if guard is None:
                 res = shard_round(
                     params, batches, weights, extras=gemd_parts,
@@ -772,10 +790,11 @@ def make_round_fn(
                     params, _, mean_loss, (num, den), cand_states = res
                 else:
                     params, _, mean_loss, (num, den) = res
-                g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+                g = _gemd_from_parts(num, den, global_dist)
                 # loss refresh stays on the client's home shard (no scatter)
-                fresh = loss_of(params, local_xs, local_ys)
-                losses = jnp.where(mask, fresh, local_losses)
+                with jax.named_scope("fl.loss_refresh"):
+                    fresh = loss_of(params, local_xs, local_ys)
+                    losses = jnp.where(mask, fresh, local_losses)
                 if stateful:
                     new_states = _algo_writeback(
                         local_states, None, cand_states, mask, scatter=False
@@ -791,14 +810,15 @@ def make_round_fn(
                  cand_states) = res
             else:
                 params, _, mean_loss, (num, den), flagged, survivors = res
-            g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+            g = _gemd_from_parts(num, den, global_dist)
             delivered = fmasks[0] if fmasks else jnp.ones_like(mask)
             refresh = (
                 mask & delivered & ~flagged
                 & (survivors >= cfg.min_survivors)
             )
-            fresh = loss_of(params, local_xs, local_ys)
-            losses = jnp.where(refresh, fresh, local_losses)
+            with jax.named_scope("fl.loss_refresh"):
+                fresh = loss_of(params, local_xs, local_ys)
+                losses = jnp.where(refresh, fresh, local_losses)
             if stateful:
                 new_states = _algo_writeback(
                     local_states, None, cand_states, refresh, scatter=False
@@ -853,18 +873,19 @@ def make_round_fn(
             sequential_clients=sequential_clients, cap=cap,
             update_transform=guard, algo=algo,
         )
-        in_cohort = jnp.any(
-            sel[None, :] == jnp.arange(c)[:, None], axis=1
-        ).reshape(n_shards, c_loc)
-        # (D, cap) local resident positions: selected-first, stable order
-        slot_pos = jnp.argsort(~in_cohort, axis=1, stable=True)[:, :cap]
-        slot_gid = slot_pos + jnp.arange(n_shards)[:, None] * c_loc
-        slot_cohort = jnp.argmax(
-            sel[None, None, :] == slot_gid[..., None], axis=-1
-        )  # (D, cap) cohort position (0 for weight-0 padding slots)
-        key_data = jax.random.key_data(jax.random.split(k_batch, k))
-        slot_keys = jax.random.wrap_key_data(key_data[slot_cohort.reshape(-1)])
-        ids = batch_indices_from_keys(cfg, slot_keys, n_c)  # (D*cap, ...) | None
+        with jax.named_scope("fl.batches"):
+            in_cohort = jnp.any(
+                sel[None, :] == jnp.arange(c)[:, None], axis=1
+            ).reshape(n_shards, c_loc)
+            # (D, cap) local resident positions: selected-first, stable order
+            slot_pos = jnp.argsort(~in_cohort, axis=1, stable=True)[:, :cap]
+            slot_gid = slot_pos + jnp.arange(n_shards)[:, None] * c_loc
+            slot_cohort = jnp.argmax(
+                sel[None, None, :] == slot_gid[..., None], axis=-1
+            )  # (D, cap) cohort position (0 for weight-0 padding slots)
+            key_data = jax.random.key_data(jax.random.split(k_batch, k))
+            slot_keys = jax.random.wrap_key_data(key_data[slot_cohort.reshape(-1)])
+            ids = batch_indices_from_keys(cfg, slot_keys, n_c)  # (D*cap, ...) | None
         flat_pos = slot_pos.reshape(-1)  # (D*cap,)
         n_ids = 0 if ids is None else 1
         # fault masks gathered to the slot layout at the jit level (the
@@ -890,16 +911,18 @@ def make_round_fn(
             gids = lax.axis_index(client_axis) * c_loc_ + jnp.arange(c_loc_)
             mask = jnp.any(sel[None, :] == gids[:, None], axis=1)
             weights = local_sizes * mask
-            slot_xs = jnp.take(local_xs, slot_index, axis=0)
-            slot_ys = jnp.take(local_ys, slot_index, axis=0)
-            batches = batches_from_indices(
-                cfg, slot_ids[0] if slot_ids else None, slot_xs, slot_ys
-            )
+            with jax.named_scope("fl.batches"):
+                slot_xs = jnp.take(local_xs, slot_index, axis=0)
+                slot_ys = jnp.take(local_ys, slot_index, axis=0)
+                batches = batches_from_indices(
+                    cfg, slot_ids[0] if slot_ids else None, slot_xs, slot_ys
+                )
             # GEMD (eq. 15) partials are unchanged from resident mode (the
             # resident-layout mask is already O(C_loc) trivia) and ride the
             # round's single psum
-            w = weights.astype(jnp.float32)
-            gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
+            with jax.named_scope("fl.gemd"):
+                w = weights.astype(jnp.float32)
+                gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
             if guard is None:
                 res = shard_round(
                     params, batches, weights, slot_index, extras=gemd_parts,
@@ -909,17 +932,18 @@ def make_round_fn(
                     params, _, mean_loss, (num, den), cand_states = res
                 else:
                     params, _, mean_loss, (num, den) = res
-                g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+                g = _gemd_from_parts(num, den, global_dist)
                 # loss refresh over slots only — the cap-not-C_loc saving
                 # applies to the refresh pass too; unselected residents keep
                 # their last known loss (scatter of distinct local positions,
                 # no collisions)
-                fresh = loss_of(params, slot_xs, slot_ys)
-                keep = jnp.take(local_losses, slot_index)
-                slot_mask = jnp.take(mask, slot_index)
-                losses = local_losses.at[slot_index].set(
-                    jnp.where(slot_mask, fresh, keep)
-                )
+                with jax.named_scope("fl.loss_refresh"):
+                    fresh = loss_of(params, slot_xs, slot_ys)
+                    keep = jnp.take(local_losses, slot_index)
+                    slot_mask = jnp.take(mask, slot_index)
+                    losses = local_losses.at[slot_index].set(
+                        jnp.where(slot_mask, fresh, keep)
+                    )
                 if stateful:
                     new_states = _algo_writeback(
                         local_states, None, cand_states, mask, scatter=False
@@ -935,7 +959,7 @@ def make_round_fn(
                  cand_states) = res
             else:
                 params, _, mean_loss, (num, den), flagged, survivors = res
-            g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+            g = _gemd_from_parts(num, den, global_dist)
             # fmasks are already slot-layout (gathered by slot_gid above)
             slot_delivered = (
                 fmasks[0] if fmasks
@@ -947,11 +971,12 @@ def make_round_fn(
                 slot_mask & slot_delivered & ~slot_flagged
                 & (survivors >= cfg.min_survivors)
             )
-            fresh = loss_of(params, slot_xs, slot_ys)
-            keep = jnp.take(local_losses, slot_index)
-            losses = local_losses.at[slot_index].set(
-                jnp.where(refresh, fresh, keep)
-            )
+            with jax.named_scope("fl.loss_refresh"):
+                fresh = loss_of(params, slot_xs, slot_ys)
+                keep = jnp.take(local_losses, slot_index)
+                losses = local_losses.at[slot_index].set(
+                    jnp.where(refresh, fresh, keep)
+                )
             if stateful:
                 # refresh scattered home to resident layout: residents no
                 # slot covered stay un-refreshed by construction
@@ -1054,14 +1079,16 @@ def make_round_fn(
             c_loc_ = local_xs.shape[0]
             gids = lax.axis_index(client_axis) * c_loc_ + jnp.arange(c_loc_)
             mask = jnp.any(sel[None, :] == gids[:, None], axis=1)
-            batches = batches_from_indices(
-                cfg, local_ids[0] if local_ids else None, local_xs, local_ys
-            )
+            with jax.named_scope("fl.batches"):
+                batches = batches_from_indices(
+                    cfg, local_ids[0] if local_ids else None, local_xs, local_ys
+                )
             weights = local_sizes * mask
             # GEMD partials stay λ-free: the metric describes the cohort's
             # label mix, not the staleness-decayed aggregation weights
-            w = weights.astype(jnp.float32)
-            gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
+            with jax.named_scope("fl.gemd"):
+                w = weights.astype(jnp.float32)
+                gemd_parts = ((w[:, None] * local_dists).sum(0), jnp.sum(w))
             if guard is None:
                 res = shard_round(
                     hist, slot_d[0], lam_d[0], batches, weights,
@@ -1071,11 +1098,12 @@ def make_round_fn(
                     params, _, mean_loss, (num, den), cand_states = res
                 else:
                     params, _, mean_loss, (num, den) = res
-                g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+                g = _gemd_from_parts(num, den, global_dist)
                 # the refresh measures the NEW aggregate on each home shard —
                 # fresh params, even when the contribution was stale
-                fresh = loss_of(params, local_xs, local_ys)
-                losses = jnp.where(mask, fresh, local_losses)
+                with jax.named_scope("fl.loss_refresh"):
+                    fresh = loss_of(params, local_xs, local_ys)
+                    losses = jnp.where(mask, fresh, local_losses)
                 if stateful:
                     new_states = _algo_writeback(
                         local_states, None, cand_states, mask, scatter=False
@@ -1092,14 +1120,15 @@ def make_round_fn(
                  cand_states) = res
             else:
                 params, _, mean_loss, (num, den), flagged, survivors = res
-            g = jnp.sum(jnp.abs(metrics_lib.safe_div(num, den) - global_dist))
+            g = _gemd_from_parts(num, den, global_dist)
             delivered = fmasks[0] if fmasks else jnp.ones_like(mask)
             refresh = (
                 mask & delivered & ~flagged
                 & (survivors >= cfg.min_survivors)
             )
-            fresh = loss_of(params, local_xs, local_ys)
-            losses = jnp.where(refresh, fresh, local_losses)
+            with jax.named_scope("fl.loss_refresh"):
+                fresh = loss_of(params, local_xs, local_ys)
+                losses = jnp.where(refresh, fresh, local_losses)
             if stateful:
                 new_states = _algo_writeback(
                     local_states, None, cand_states, refresh, scatter=False
@@ -1178,19 +1207,20 @@ def make_round_fn(
                 jax.random.fold_in(state.key, faults_lib.FAULT_SALT),
                 fault_model, cfg.num_clients, n_sh, lemons,
             )
-        sel_args = (k_sel, state.selection_state())
-        if route_avail:
-            # quarantined clients are "unavailable" to selection — the same
-            # availability hook the scenario uses, masks AND-composed
-            sel_mask = avail
-            if guard_on:
-                q_ok = state.quarantine <= 0
-                sel_mask = q_ok if sel_mask is None else (sel_mask & q_ok)
-            sel_args = sel_args + (sel_mask,)
-        if len(branches) == 1:
-            sel = branches[0](*sel_args)
-        else:
-            sel = lax.switch(state.strategy_index, branches, *sel_args)
+        with jax.named_scope("fl.select"):
+            sel_args = (k_sel, state.selection_state())
+            if route_avail:
+                # quarantined clients are "unavailable" to selection — the
+                # same availability hook the scenario uses, masks AND-composed
+                sel_mask = avail
+                if guard_on:
+                    q_ok = state.quarantine <= 0
+                    sel_mask = q_ok if sel_mask is None else (sel_mask & q_ok)
+                sel_args = sel_args + (sel_mask,)
+            if len(branches) == 1:
+                sel = branches[0](*sel_args)
+            else:
+                sel = lax.switch(state.strategy_index, branches, *sel_args)
         hist = new_s = sim_time = None
         flagged_c = survivors = None
         new_algo = None
@@ -1240,12 +1270,13 @@ def make_round_fn(
             else:
                 exs = state.client_xs.reshape((-1,) + state.client_xs.shape[2:])
                 eys = state.client_ys.reshape(-1)
-            acc = lax.cond(
-                t % cfg.eval_every == 0,
-                lambda p: jnp.asarray(accuracy_fn(p, exs, eys), jnp.float32),
-                lambda p: jnp.float32(jnp.nan),
-                params,
-            )
+            with jax.named_scope("fl.eval"):
+                acc = lax.cond(
+                    t % cfg.eval_every == 0,
+                    lambda p: jnp.asarray(accuracy_fn(p, exs, eys), jnp.float32),
+                    lambda p: jnp.float32(jnp.nan),
+                    params,
+                )
 
         updates = dict(params=params, key=key, round=t, losses=losses)
         if hist is not None:
@@ -1332,9 +1363,11 @@ def _scanned(round_fn, num_rounds: int):
     cache = _programs(round_fn)
     key = ("scan", num_rounds)
     if key not in cache:
-        cache[key] = jax.jit(
-            lambda state: lax.scan(round_fn, state, None, length=num_rounds)
-        )
+
+        def fl_scan(state):
+            return lax.scan(round_fn, state, None, length=num_rounds)
+
+        cache[key] = jax.jit(fl_scan)
     return cache[key]
 
 
@@ -1365,7 +1398,7 @@ def run_scanned(
     """
     if mesh is not None:
         state = shard_server_state(state, mesh, client_axis)
-    with obs_tracing_lib.annotate(f"fl.scan_chunk[{num_rounds}]"):
+    with obs_tracing_lib.annotate("fl.chunk"):
         state, outputs = _scanned(round_fn, num_rounds)(state)
     if sink is not None and num_rounds:
         obs_sink_lib.drain_fl_outputs(sink, outputs)
@@ -1376,9 +1409,11 @@ def _vmapped(round_fn, num_rounds: int):
     cache = _programs(round_fn)
     key = ("vmap", num_rounds)
     if key not in cache:
-        cache[key] = jax.jit(
-            jax.vmap(lambda state: lax.scan(round_fn, state, None, length=num_rounds))
-        )
+
+        def fl_run_many(state):
+            return lax.scan(round_fn, state, None, length=num_rounds)
+
+        cache[key] = jax.jit(jax.vmap(fl_run_many))
     return cache[key]
 
 
@@ -1407,7 +1442,8 @@ def run_many(
         stacked_state = shard_server_state(
             stacked_state, mesh, client_axis, batch_dims=1
         )
-    return _vmapped(round_fn, num_rounds)(stacked_state)
+    with obs_tracing_lib.annotate("fl.chunk"):
+        return _vmapped(round_fn, num_rounds)(stacked_state)
 
 
 # -------------------------------------------------------------- crash-resume
@@ -1714,112 +1750,126 @@ def init_server_state(
     Q-candidate block instead — this path never materialises a C×C array,
     and passing a precomputed full-federation ``kernel``/``eig_state`` is a
     :class:`ValueError`.
+
+    Under a profiler trace the call is one ``fl.init`` span whose child
+    spans, one per phase, cover it (DESIGN.md §14).
     """
-    client_xs = jnp.asarray(client_xs)
-    client_ys = jnp.asarray(client_ys)
-    c, n_c = client_xs.shape[0], client_xs.shape[1]
-    if profiles is None:
-        assert feature_fn is not None, "need feature_fn to compute profiles"
-        profiles = profiles_lib.profile_all_clients(
-            jax.jit(feature_fn), params, list(client_xs)
-        )
-    if losses is None:
-        losses = jax.jit(jax.vmap(loss_fn, in_axes=(None, 0, 0)))(
-            params, client_xs, client_ys
-        )
-    candidates = None
-    if cfg.candidate_frac is not None:
-        # Funnel init (DESIGN.md §10): losses come FIRST (they are the
-        # stage-1 prefilter score), then every kernel-shaped piece lives on
-        # the Q-block — this path never materialises a C×C array.
-        if kernel is not None or eig_state is not None:
-            raise ValueError(
-                "candidate_frac is set: the kernel and spectral cache are "
-                "funnel-owned (Q×Q, rebuilt with the candidates) — don't "
-                "pass precomputed full-federation kernel/eig_state"
-            )
-        candidates, kernel, eig_state = funnel_fields(
-            cfg,
-            key if key is not None else jax.random.key(cfg.seed),
-            profiles, losses, strategy=strategy,
-            mesh=mesh, client_axis=client_axis,
-        )
-    if kernel is None:
-        kernel = similarity_lib.kernel_from_profiles(
-            profiles, use_kernel=cfg.use_pallas_kernel
-        )
-    if eig_state is None:
-        # Pay the O(C³) decomposition only when the strategy's select_fn
-        # actually draws from the cache; strategy=None (unknown — e.g. a
-        # caller assembling a multi-strategy run_many grid) keeps the real
-        # spectrum as the safe default.  The identity placeholder shares the
-        # pytree layout, so lax.switch grids stay shape-stable either way.
-        if strategy is None or getattr(strategy, "uses_spectral_cache", False):
-            eig_state = dpp_lib.kdpp_sampler_state(kernel, cfg.clients_per_round)
-        else:
-            eig_state = dpp_lib.identity_sampler_state(c, cfg.clients_per_round)
-    if cluster_labels is None:
-        if isinstance(strategy, selection_lib.ClusterSelection):
+    with obs_tracing_lib.annotate("fl.init"):
+        client_xs = jnp.asarray(client_xs)
+        client_ys = jnp.asarray(client_ys)
+        c, n_c = client_xs.shape[0], client_xs.shape[1]
+        if profiles is None:
+            assert feature_fn is not None, "need feature_fn to compute profiles"
+            with obs_tracing_lib.annotate("fl.init.profiles"):
+                profiles = profiles_lib.profile_all_clients(
+                    jax.jit(feature_fn), params, list(client_xs)
+                )
+        if losses is None:
+            with obs_tracing_lib.annotate("fl.init.losses"):
+                losses = jax.jit(jax.vmap(loss_fn, in_axes=(None, 0, 0)))(
+                    params, client_xs, client_ys
+                )
+        candidates = None
+        if cfg.candidate_frac is not None:
+            # Funnel init (DESIGN.md §10): losses come FIRST (they are the
+            # stage-1 prefilter score), then every kernel-shaped piece lives
+            # on the Q-block — this path never materialises a C×C array.
+            if kernel is not None or eig_state is not None:
+                raise ValueError(
+                    "candidate_frac is set: the kernel and spectral cache are "
+                    "funnel-owned (Q×Q, rebuilt with the candidates) — don't "
+                    "pass precomputed full-federation kernel/eig_state"
+                )
+            with obs_tracing_lib.annotate("fl.init.funnel"):
+                candidates, kernel, eig_state = funnel_fields(
+                    cfg,
+                    key if key is not None else jax.random.key(cfg.seed),
+                    profiles, losses, strategy=strategy,
+                    mesh=mesh, client_axis=client_axis,
+                )
+        if kernel is None:
+            with obs_tracing_lib.annotate("fl.init.kernel"):
+                kernel = similarity_lib.kernel_from_profiles(
+                    profiles, use_kernel=cfg.use_pallas_kernel
+                )
+        if eig_state is None:
+            # Pay the O(C³) decomposition only when the strategy's select_fn
+            # actually draws from the cache; strategy=None (unknown — e.g. a
+            # caller assembling a multi-strategy run_many grid) keeps the
+            # real spectrum as the safe default.  The identity placeholder
+            # shares the pytree layout, so lax.switch grids stay shape-stable
+            # either way.
+            with obs_tracing_lib.annotate("fl.init.spectral"):
+                if strategy is None or getattr(strategy, "uses_spectral_cache", False):
+                    eig_state = dpp_lib.kdpp_sampler_state(kernel, cfg.clients_per_round)
+                else:
+                    eig_state = dpp_lib.identity_sampler_state(c, cfg.clients_per_round)
+        if cluster_labels is None and isinstance(strategy, selection_lib.ClusterSelection):
             # funnel mode fits the clusters on the SAME fingerprints as the
             # unfunneled path, restricted to the candidate rows — with
             # candidates == arange(C) (Q=C) the labels are bit-identical
-            idx = (
-                range(c) if candidates is None
-                else np.asarray(candidates).tolist()
-            )
-            gp = jnp.stack([
-                profiles_lib.representative_gradient_profile(
-                    loss_fn, params, client_xs[i], client_ys[i]
+            with obs_tracing_lib.annotate("fl.init.clusters"):
+                idx = (
+                    range(c) if candidates is None
+                    else np.asarray(candidates).tolist()
                 )
-                for i in idx
+                gp = jnp.stack([
+                    profiles_lib.representative_gradient_profile(
+                        loss_fn, params, client_xs[i], client_ys[i]
+                    )
+                    for i in idx
+                ])
+                cluster_labels = strategy.fit(gp, cfg.clients_per_round)
+        with obs_tracing_lib.annotate("fl.init.label_dists"):
+            label_dists = jnp.stack([
+                metrics_lib.label_distribution(client_ys[i], cfg.num_classes)
+                for i in range(c)
             ])
-            cluster_labels = strategy.fit(gp, cfg.clients_per_round)
-        else:
-            n_lbl = c if candidates is None else candidates.shape[0]
-            cluster_labels = jnp.zeros((n_lbl,), jnp.int32)
-    label_dists = jnp.stack([
-        metrics_lib.label_distribution(client_ys[i], cfg.num_classes)
-        for i in range(c)
-    ])
-    global_dist = metrics_lib.label_distribution(
-        client_ys.reshape(-1), cfg.num_classes
-    )
-    param_hist = shard_staleness = None
-    if cfg.staleness_bound is not None:
-        param_hist, shard_staleness = staleness_lib.init_staleness_fields(
-            params, cfg.staleness_bound, mesh, client_axis
-        )
-    # quarantine counters only exist on guarded configs so the pytree (and
-    # every compiled program keyed on it) is unchanged for fault-free runs
-    quarantine = jnp.zeros((c,), jnp.int32) if cfg.guarded() else None
-    # per-client algorithm state only exists for stateful algorithms
-    # (DESIGN.md §12) — None keeps the pytree unchanged for fedavg/fedprox
-    algo_state = local_algos_lib.init_client_states(
-        cfg.local_algo_obj(), params, c
-    )
-    state = ServerState(
-        params=params,
-        key=key if key is not None else jax.random.key(cfg.seed),
-        round=jnp.asarray(0, jnp.int32),
-        losses=losses,
-        kernel=kernel,
-        profiles=profiles,
-        eig_state=eig_state,
-        cluster_labels=cluster_labels,
-        client_xs=client_xs,
-        client_ys=client_ys,
-        client_sizes=jnp.full((c,), float(n_c)),
-        client_label_dists=label_dists,
-        global_label_dist=global_dist,
-        strategy_index=jnp.asarray(strategy_index, jnp.int32),
-        param_hist=param_hist,
-        shard_staleness=shard_staleness,
-        candidates=candidates,
-        quarantine=quarantine,
-        algo_state=algo_state,
-    )
-    if mesh is not None:
-        state = shard_server_state(state, mesh, client_axis)
+            global_dist = metrics_lib.label_distribution(
+                client_ys.reshape(-1), cfg.num_classes
+            )
+        with obs_tracing_lib.annotate("fl.init.state"):
+            if cluster_labels is None:
+                n_lbl = c if candidates is None else candidates.shape[0]
+                cluster_labels = jnp.zeros((n_lbl,), jnp.int32)
+            param_hist = shard_staleness = None
+            if cfg.staleness_bound is not None:
+                param_hist, shard_staleness = staleness_lib.init_staleness_fields(
+                    params, cfg.staleness_bound, mesh, client_axis
+                )
+            # quarantine counters only exist on guarded configs so the pytree
+            # (and every compiled program keyed on it) is unchanged for
+            # fault-free runs
+            quarantine = jnp.zeros((c,), jnp.int32) if cfg.guarded() else None
+            # per-client algorithm state only exists for stateful algorithms
+            # (DESIGN.md §12) — None keeps the pytree unchanged for
+            # fedavg/fedprox
+            algo_state = local_algos_lib.init_client_states(
+                cfg.local_algo_obj(), params, c
+            )
+            state = ServerState(
+                params=params,
+                key=key if key is not None else jax.random.key(cfg.seed),
+                round=jnp.asarray(0, jnp.int32),
+                losses=losses,
+                kernel=kernel,
+                profiles=profiles,
+                eig_state=eig_state,
+                cluster_labels=cluster_labels,
+                client_xs=client_xs,
+                client_ys=client_ys,
+                client_sizes=jnp.full((c,), float(n_c)),
+                client_label_dists=label_dists,
+                global_label_dist=global_dist,
+                strategy_index=jnp.asarray(strategy_index, jnp.int32),
+                param_hist=param_hist,
+                shard_staleness=shard_staleness,
+                candidates=candidates,
+                quarantine=quarantine,
+                algo_state=algo_state,
+            )
+            if mesh is not None:
+                state = shard_server_state(state, mesh, client_axis)
     return state
 
 

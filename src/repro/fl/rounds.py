@@ -240,39 +240,44 @@ def build_client_parallel_round(
         client_states=None,
     ):
         n_clients = client_weights.shape[0]
-        per_client = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (n_clients,) + x.shape), global_params
-        )
-        if client_constraint is not None:
-            per_client = client_constraint(per_client)
-        operands = (
-            (per_client, client_states, client_batches)
-            if stateful
-            else (per_client, client_batches)
-        )
-        if sequential_clients:
-            # CPU-simulation path: vmapped convs lower to grouped convolutions
-            # (XLA-CPU pathology, ~10x slow); on the mesh each device owns one
-            # client so vmap is right there, lax.map is right here.
-            out = jax.lax.map(lambda args: local_update(*args), operands)
-        else:
-            out = jax.vmap(local_update)(*operands)
+        with jax.named_scope("fl.local_update"):
+            per_client = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (n_clients,) + x.shape), global_params
+            )
+            if client_constraint is not None:
+                per_client = client_constraint(per_client)
+            operands = (
+                (per_client, client_states, client_batches)
+                if stateful
+                else (per_client, client_batches)
+            )
+            if sequential_clients:
+                # CPU-simulation path: vmapped convs lower to grouped
+                # convolutions (XLA-CPU pathology, ~10x slow); on the mesh
+                # each device owns one client so vmap is right there,
+                # lax.map is right here.
+                out = jax.lax.map(lambda args: local_update(*args), operands)
+            else:
+                out = jax.vmap(local_update)(*operands)
         if stateful:
             new_params, new_states, losses = out
         else:
             new_params, losses = out
         if update_transform is None:
-            agg = weighted_average(new_params, client_weights)
+            with jax.named_scope("fl.aggregate"):
+                agg = weighted_average(new_params, client_weights)
+                mean_loss = jnp.mean(losses)
             if stateful:
-                return agg, jnp.mean(losses), new_states
-            return agg, jnp.mean(losses)
-        new_params, w, losses, flagged = update_transform(
-            new_params, global_params, client_weights, losses, *guard_args
-        )
-        agg = weighted_average(new_params, w)
-        entry = jnp.mean(losses, axis=tuple(range(1, losses.ndim)))
-        mean_loss = finite_mean(entry, where=w > 0)
-        survivors = jnp.sum((w > 0).astype(jnp.int32))
+                return agg, mean_loss, new_states
+            return agg, mean_loss
+        with jax.named_scope("fl.aggregate"):
+            new_params, w, losses, flagged = update_transform(
+                new_params, global_params, client_weights, losses, *guard_args
+            )
+            agg = weighted_average(new_params, w)
+            entry = jnp.mean(losses, axis=tuple(range(1, losses.ndim)))
+            mean_loss = finite_mean(entry, where=w > 0)
+            survivors = jnp.sum((w > 0).astype(jnp.int32))
         if stateful:
             return agg, mean_loss, flagged, survivors, new_states
         return agg, mean_loss, flagged, survivors
@@ -361,22 +366,24 @@ def build_shard_cohort_round(
     stateful = algo is not None and algo.stateful
 
     def _updates(global_params, batches, n, states=None):
-        per_client = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (n,) + x.shape), global_params
-        )
-        operands = (
-            (per_client, states, batches) if stateful else (per_client, batches)
-        )
-        if sequential_clients:
-            out = jax.lax.map(lambda args: local_update(*args), operands)
-        else:
-            out = jax.vmap(local_update)(*operands)
+        with jax.named_scope("fl.local_update"):
+            per_client = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (n,) + x.shape), global_params
+            )
+            operands = (
+                (per_client, states, batches) if stateful else (per_client, batches)
+            )
+            if sequential_clients:
+                out = jax.lax.map(lambda args: local_update(*args), operands)
+            else:
+                out = jax.vmap(local_update)(*operands)
         if stateful:
             new_params, new_states, losses = out
             return new_params, losses, new_states
         new_params, losses = out
         return new_params, losses, None
 
+    @jax.named_scope("fl.aggregate")
     def _aggregate(new_params, losses, weights, extras, survivors_local=None):
         # eq. (6) as partial weighted sums: Σ_c w_c·θ_c / Σ_c w_c.  ALL the
         # round's partial reductions ride ONE psum call so the per-round
@@ -426,10 +433,11 @@ def build_shard_cohort_round(
         if update_transform is None:
             out = _aggregate(new_params, losses, local_weights, extras)
             return out + (new_states,) if stateful else out
-        new_params, w, losses, flagged = update_transform(
-            new_params, global_params, local_weights, losses, *guard_args
-        )
-        survivors_local = jnp.sum((w > 0).astype(jnp.int32))
+        with jax.named_scope("fl.aggregate"):
+            new_params, w, losses, flagged = update_transform(
+                new_params, global_params, local_weights, losses, *guard_args
+            )
+            survivors_local = jnp.sum((w > 0).astype(jnp.int32))
         agg, client_losses, mean_loss, extras, survivors = _aggregate(
             new_params, losses, w, extras, survivors_local
         )
@@ -462,10 +470,11 @@ def build_shard_cohort_round(
             new_states = None
         slot_weights = jnp.take(local_weights, slot_index)
         if update_transform is not None:
-            new_params, slot_weights, losses, slot_flagged = update_transform(
-                new_params, global_params, slot_weights, losses, *guard_args
-            )
-            survivors_local = jnp.sum((slot_weights > 0).astype(jnp.int32))
+            with jax.named_scope("fl.aggregate"):
+                new_params, slot_weights, losses, slot_flagged = update_transform(
+                    new_params, global_params, slot_weights, losses, *guard_args
+                )
+                survivors_local = jnp.sum((slot_weights > 0).astype(jnp.int32))
             agg, slot_losses, mean_loss, extras, survivors = _aggregate(
                 new_params, losses, slot_weights, extras, survivors_local
             )

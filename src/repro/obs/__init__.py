@@ -14,7 +14,8 @@ program:
   admit / harvest boundaries only — never from inside a scan body.
 * :mod:`repro.obs.tracing` — thin ``jax.profiler`` wrappers
   (:func:`trace`, :func:`annotate`) with no-op fallbacks, so profiler
-  support costs nothing when no trace is active.
+  support costs nothing when no trace is active, and the ``obs.compile``
+  marker recorded at every compile or compile-cache load.
 
 This package depends only on jax/numpy/stdlib — ``fl/`` and ``serve/``
 import it, never the reverse.
