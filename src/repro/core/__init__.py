@@ -13,11 +13,17 @@ from repro.core.dpp import (
     sample_kdpp,
     sample_kdpp_from_eigh,
 )
-from repro.core.metrics import cohort_label_distribution, gemd, label_distribution
+from repro.core.metrics import (
+    cohort_label_distribution,
+    gemd,
+    label_distribution,
+    label_distributions,
+)
 from repro.core.profiles import (
     fc1_profile,
     gradient_profile,
     profile_all_clients,
+    profile_stacked_clients,
     representative_gradient_profile,
 )
 from repro.core.selection import (

@@ -20,17 +20,20 @@ representative-gradient profiles (Fraboni et al., ICML'21).
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Iterable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 __all__ = [
     "fc1_profile",
     "gradient_profile",
     "representative_gradient_profile",
     "profile_all_clients",
+    "profile_stacked_clients",
 ]
 
 FeatureFn = Callable[..., Tuple[jax.Array, jax.Array]]
@@ -110,7 +113,54 @@ def profile_all_clients(
     """Stack eq.-(11) profiles for every client: -> (C, Q).
 
     In deployment each client computes its own row locally and uploads it once
-    (Algorithm 1 lines 2-4); here we loop over the simulated clients.
+    (Algorithm 1 lines 2-4); here we loop over the simulated clients, which
+    may hold different numbers of samples.  A stacked federation takes
+    :func:`profile_stacked_clients` instead: one dispatch, not a loop.
     """
     rows = [fc1_profile(feature_fn, params, xs, batch_size=batch_size) for xs in client_data]
     return jnp.stack(rows, axis=0)
+
+
+@functools.partial(jax.jit, static_argnums=0, static_argnames="batch_size")
+def profile_stacked_clients(
+    feature_fn: FeatureFn, params, client_xs: jax.Array, batch_size: int = 256
+) -> jax.Array:
+    """Eq.-(11) profiles of a stacked ``(C, n, ...)`` federation -> (C, Q),
+    in one dispatch.
+
+    The same mean as :func:`fc1_profile`, as one compiled program per
+    ``(feature_fn, batch_size)`` and shape (jit's cache on the static
+    arguments), so a new federation with new params reuses the executable.
+    Memory is bounded by one block of samples, as in the per-client loop:
+    ``lax.map`` runs over the clients and, inside a client, a scan over
+    blocks of ``min(batch_size, n)`` samples; the ragged tail block is
+    zero-padded and its padded rows are masked out of the sum, so they add
+    exactly zero.  ``n == 0`` gives the zero profile of width Q per client.
+    """
+    c, n = client_xs.shape[:2]
+    sample = client_xs.shape[2:]
+    bs = max(1, min(batch_size, n))
+    probe = jax.eval_shape(
+        lambda p, xb: feature_fn(p, xb)[1],
+        params, jax.ShapeDtypeStruct((bs, *sample), client_xs.dtype),
+    )
+    width = int(np.prod(probe.shape[1:]))
+    if n == 0:
+        return jnp.zeros((c, width), probe.dtype)
+    nb = -(-n // bs)
+    keep = (jnp.arange(nb * bs) < n).reshape(nb, bs, 1)
+
+    def client_profile(xs):
+        pad = jnp.zeros((nb * bs - n, *sample), xs.dtype)
+        blocks = jnp.concatenate([xs, pad]).reshape(nb, bs, *sample)
+
+        def add_block(total, block):
+            xb, kb = block
+            _, feats = feature_fn(params, xb)
+            feats = jnp.where(kb, feats.reshape(bs, width), 0)
+            return total + jnp.sum(feats, axis=0), None
+
+        total, _ = lax.scan(add_block, jnp.zeros((width,), probe.dtype), (blocks, keep))
+        return total / n
+
+    return lax.map(client_profile, client_xs)

@@ -1761,8 +1761,8 @@ def init_server_state(
         if profiles is None:
             assert feature_fn is not None, "need feature_fn to compute profiles"
             with obs_tracing_lib.annotate("fl.init.profiles"):
-                profiles = profiles_lib.profile_all_clients(
-                    jax.jit(feature_fn), params, list(client_xs)
+                profiles = profiles_lib.profile_stacked_clients(
+                    feature_fn, params, client_xs
                 )
         if losses is None:
             with obs_tracing_lib.annotate("fl.init.losses"):
@@ -1821,10 +1821,7 @@ def init_server_state(
                 ])
                 cluster_labels = strategy.fit(gp, cfg.clients_per_round)
         with obs_tracing_lib.annotate("fl.init.label_dists"):
-            label_dists = jnp.stack([
-                metrics_lib.label_distribution(client_ys[i], cfg.num_classes)
-                for i in range(c)
-            ])
+            label_dists = metrics_lib.label_distributions(client_ys, cfg.num_classes)
             global_dist = metrics_lib.label_distribution(
                 client_ys.reshape(-1), cfg.num_classes
             )

@@ -161,11 +161,8 @@ class FLTrainer:
 
         n_c = client_xs.shape[1]
         self.client_sizes = jnp.full((cfg.num_clients,), float(n_c))
-        self.client_label_dists = jnp.stack(
-            [
-                metrics_lib.label_distribution(self.client_ys[c], cfg.num_classes)
-                for c in range(cfg.num_clients)
-            ]
+        self.client_label_dists = metrics_lib.label_distributions(
+            self.client_ys, cfg.num_classes
         )
         self.global_label_dist = metrics_lib.label_distribution(
             self.client_ys.reshape(-1), cfg.num_classes
@@ -194,8 +191,8 @@ class FLTrainer:
 
     def _init_profiles(self):
         """Alg. 1 lines 2-5: one-shot FC-1 profiling + kernel construction."""
-        feats = profiles_lib.profile_all_clients(
-            jax.jit(self.feature_fn), self.params, list(self.client_xs)
+        feats = profiles_lib.profile_stacked_clients(
+            self.feature_fn, self.params, self.client_xs
         )
         self.round_state.profiles = feats
         if self.cfg.candidate_frac is None:
