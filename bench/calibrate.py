@@ -18,9 +18,10 @@ control), and planted faults in the program's place: the reference with
 each client's batch cut to its first half (the mean taken over the rest),
 the reference's eq.-14 kernel with two of round 1's clients exchanged, and
 the program's accuracy reading with the test labels shifted by one sample
-(an answer altered where it is produced).  It also reads the first
-chunk's accuracy once per federation and by one ``jax.vmap`` over the
-batch, for comparison.  A program step that returns its
+(an answer altered where it is produced).  Where the adapter offers a
+one-federation ``accuracy``, it also reads the first chunk's accuracy once
+per federation and by one ``jax.vmap`` of it over the batch, for
+comparison.  A program step that returns its
 state unchanged reads 1 on ``update_gap`` by construction and needs no
 run.  ``checked`` is each number's largest over the federations that a
 run with this seed would check.  Each result is one JSON line on standard
@@ -52,13 +53,12 @@ def _setup():
     os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = str(4 << 30)
 
 
-def _deployment(cell, seed):
+def _deployment(model, cell, seed):
     import jax
 
-    from bench.data import make_deployment
     from bench.federations import derive_key
 
-    return make_deployment(cell.cfg, jax.random.key(derive_key(seed, 0)))
+    return model.make_deployment(cell.cfg, jax.random.key(derive_key(seed, 0)))
 
 
 def _memory_peak():
@@ -80,7 +80,7 @@ def curves(cell, seeds, strategies, lockstep, write_target=False):
         system = model.System(cfg, strategy)
         traffic = dict(cell.traffic, strategy=strategy, lockstep=lockstep)
         for seed in seeds:
-            runner = Runner(system, cfg, traffic, _deployment(cell, seed), seed)
+            runner = Runner(system, cfg, traffic, _deployment(model, cell, seed), seed)
             accs = []
             orig = system.accuracy
 
@@ -130,7 +130,6 @@ def limits(cell, seeds):
 
     from bench import check, harness
     from bench.federations import Runner
-    from repro.models import cnn
 
     cfg = cell.cfg
     model = harness.load_module("models", cfg["model"], cell.root)
@@ -138,7 +137,7 @@ def limits(cell, seeds):
     system = model.System(cfg, cell.traffic["strategy"])
     for seed in seeds:
         t0 = time.perf_counter()
-        runner = Runner(system, cfg, cell.traffic, _deployment(cell, seed), seed)
+        runner = Runner(system, cfg, cell.traffic, _deployment(model, cell, seed), seed)
         runner.run_batch(0)
         t1 = time.perf_counter()
         out = {"mode": "limits", "workload": cell.name, "seed": seed,
@@ -153,8 +152,8 @@ def limits(cell, seeds):
             ctrl = harness.reference_run(ref, cfg, runner, batch, slot, snap, dtype=jnp.bfloat16)
             half = harness.reference_run(ref, cfg, runner, batch, slot, snap, keep=0.5)
             swapped = dict(want, kernel=_swap_clients(want["kernel"], snap["selected"][0][:2]))
-            misread = dict(snap, judged_acc=float(cnn.accuracy(
-                snap["judged_params"], runner.test_xs, jnp.roll(runner.test_ys, 1))))
+            misread = dict(snap, judged_acc=float(system.accuracy(
+                snap["judged_params"], runner.test_xs, jnp.roll(runner.test_ys, 1), 1)))
             rec = {
                 "federation": [batch, slot],
                 "program": check.compare(snap, want),
@@ -180,11 +179,12 @@ def limits(cell, seeds):
         # the first chunk's params, read one federation at a time (as the
         # harness reads them) and by one vmapped call over the batch
         stacked = runner.snapshots[(0, 0)]["params"]
-        if runner.lockstep > 1:
+        one = getattr(model, "accuracy", None)
+        if runner.lockstep > 1 and one is not None:
             out["first_chunk_acc"] = {
                 "per_federation": [float(x) for x in system.accuracy(
                     stacked, runner.test_xs, runner.test_ys, runner.lockstep)],
-                "vmapped": [float(x) for x in jax.vmap(cnn.accuracy, in_axes=(0, None, None))(
+                "vmapped": [float(x) for x in jax.vmap(one, in_axes=(0, None, None))(
                     stacked, runner.test_xs, runner.test_ys)]}
         out["memory_peak_bytes"] = _memory_peak()
         out["seconds"] = time.perf_counter() - t0

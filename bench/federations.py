@@ -89,12 +89,16 @@ class Runner:
         while rounds < self.max_rounds:
             with _span("bench.chunk"):
                 state, outs = sysm.run_chunk(state, self.chunk, s_count)
+                # the accuracy reading is queued behind the chunk before the
+                # host waits on the chunk's outputs, so the part of its
+                # dispatch that does not itself wait for the chunk runs
+                # while the chip computes
+                acc = sysm.accuracy(state.params, self.test_xs, self.test_ys, s_count)
                 selected = np.asarray(outs["selected"])
                 loss = np.asarray(outs["loss"])
             rounds += self.chunk
             with _span("bench.eval"):
-                acc = np.atleast_1d(np.asarray(
-                    sysm.accuracy(state.params, self.test_xs, self.test_ys, s_count)))
+                acc = np.atleast_1d(np.asarray(acc))
             self.invalid_rounds += check.cohort_invalid(
                 selected, self.cfg["clients_per_round"], self.cfg["num_clients"])
             if snapshot and rounds == self.chunk:

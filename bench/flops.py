@@ -106,10 +106,10 @@ def roofline_seconds(flops: float, nbytes: float, peak: dict) -> float:
     return max(flops / peak["bf16_flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
 
 
-def rounds_flops(cfg: dict, rounds: int) -> int:
-    """FLOPs of a federation's first ``rounds`` rounds (evaluations on
-    every ``eval_every``-th)."""
-    every = cfg["eval_every"]
-    evals = rounds // every
-    return (rounds - evals) * round_flops(cfg, False) + evals * round_flops(cfg, True)
+def rounds_flops(per_round, eval_every: int, rounds: int) -> int:
+    """FLOPs of a federation's first ``rounds`` rounds, given the count of
+    one round, ``per_round(eval_round)``: an evaluation on every
+    ``eval_every``-th round."""
+    evals = rounds // eval_every
+    return (rounds - evals) * per_round(False) + evals * per_round(True)
 

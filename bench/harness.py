@@ -14,6 +14,48 @@ file of its own, found by the name in ``BENCHMARK.json``:
 * ``bench/metrics/<metric>.py``: one per-layer metric's reader, a function
   ``read(ctx)`` that returns the value or ``None`` when its cell has
   nothing for it to read.
+
+Nothing here, in ``run.py``, ``federations.py``, ``calibrate.py``,
+``tiny.py`` or a metric's reader names a model or reads a key of one model
+family.  What a model needs, its adapter and its reference give.
+
+A program adapter, ``bench/models/<model>.py``, exports:
+
+* ``make_deployment(cfg, key) -> (client_xs, client_ys, test_xs, test_ys)``:
+  the clients' data, stacked ``(C, n, ...)``, their labels ``(C, n)`` (the
+  label histograms and eq. 15 read them), and the held-out set, made on the
+  device from ``key``;
+* ``TINY_SIZES``: the keys of the configuration to override so that the
+  CPU tests run the cell in seconds (``bench/tiny.py``);
+* ``System(cfg, strategy)``, the program built through its normal path,
+  with ``init_params(key)``, ``init_state(params, key, client_xs,
+  client_ys)`` (``init_server_state``), ``stack(states)``,
+  ``run_chunk(state, rounds, lockstep) -> (state, outputs)`` (``run_scanned``
+  or ``run_many``; the outputs hold ``selected`` and ``loss`` per round),
+  ``accuracy(params, test_xs, test_ys, lockstep)`` (one reading per
+  federation), and the counts from shapes: ``round_flops(eval_round)``,
+  ``init_flops()`` and ``eq14_kernels()`` (``bench/flops.py``);
+* optionally ``accuracy(params, test_xs, test_ys)``: one federation's
+  reading, which ``calibrate.py limits`` also takes under ``jax.vmap``.
+
+A plain reference, ``bench/references/<reference>.py``, imports nothing of
+the program and exports, each reading its sizes and settings from ``cfg``:
+
+* ``init_params(key, cfg)``: the initial weights by the program's recipe;
+* ``profiles(params, client_xs, cfg, dtype)``: the (C, F) eq.-(11)
+  profiles;
+* ``replay(params0, client_xs, client_ys, cohorts, cfg, dtype, keep) ->
+  (params, loss)``: the rounds with the given (R, k) cohorts, each client
+  on the first ``keep`` share of its samples, and each round's mean local
+  loss;
+* ``accuracy(params, xs, ys, cfg, dtype)``: the held-out accuracy.
+
+``dtype`` is ``float32`` or, for the control, ``bfloat16``; ``keep`` < 1
+plants a fault.  The eq.-(14) kernel is model-free (``bench/eq14.py``).
+
+A per-layer metric with no ``workloads`` list in ``BENCHMARK.json`` is
+read in every cell, those that later configurations add too, so its reader
+may use only what every adapter gives.
 """
 
 from __future__ import annotations
@@ -29,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import check, flops
+from bench.eq14 import eq14_kernel
 from bench.federations import Runner
 
 __all__ = ["ROOT", "Cell", "load_cell", "load_module", "MetricContext", "read_metrics",
@@ -122,17 +165,14 @@ def reference_run(ref, cfg, runner: Runner, batch: int, slot: int, snap: dict,
     the rounds replayed with the program's cohorts, and the held-out
     accuracy of the params on which the program's stopping rule read its
     own.  ``dtype`` and ``keep`` give the control and a planted fault."""
-    prec = cfg["matmul_precision"]
     pk, _ = runner.keys(batch, slot)
     p0 = ref.init_params(jax.random.key(pk), cfg)
-    prof = ref.profiles(p0, runner.client_xs, dtype=dtype, precision=prec)
-    kernel = ref.eq14_kernel(prof, dtype=dtype)
+    prof = ref.profiles(p0, runner.client_xs, cfg, dtype=dtype)
+    kernel = eq14_kernel(prof, dtype=dtype)
     params, loss = ref.replay(p0, runner.client_xs, runner.client_ys,
-                              jnp.asarray(snap["selected"], jnp.int32), lr=float(cfg["lr"]),
-                              steps=int(cfg["local_epochs"]), dtype=dtype, precision=prec,
+                              jnp.asarray(snap["selected"], jnp.int32), cfg, dtype=dtype,
                               keep=keep)
-    acc = ref.accuracy(snap["judged_params"], runner.test_xs, runner.test_ys, dtype=dtype,
-                       precision=prec)
+    acc = ref.accuracy(snap["judged_params"], runner.test_xs, runner.test_ys, cfg, dtype=dtype)
     return {"params0": p0, "params": params, "loss": np.asarray(loss),
             "judged_acc": float(acc), "kernel": kernel}
 
@@ -163,7 +203,11 @@ class MetricContext:
         return (hi - lo) / 1e9
 
     def round_flops_total(self) -> float:
-        return float(sum(flops.rounds_flops(self.cfg, r.rounds_run) for r in self.records))
+        """FLOPs of the window's federation-rounds, by the adapter's count
+        of one round."""
+        every = int(self.cfg["eval_every"])
+        return float(sum(flops.rounds_flops(self.system.round_flops, every, r.rounds_run)
+                         for r in self.records))
 
 
 def read_metrics(cell: Cell, ctx: MetricContext) -> Dict[str, dict]:

@@ -12,6 +12,9 @@ Two departures, both forced by the program and kept to the harness's side:
   held-out set after each chunk instead, which is also where the stopping
   rule reads it.
 * A lockstep batch's accuracy is read one federation at a time.
+
+The deployment is ``bench/data.py``'s synthetic images; ``TINY_SIZES`` cut
+the configuration for the CPU tests.
 """
 
 from __future__ import annotations
@@ -24,8 +27,20 @@ from repro.fl import engine
 from repro.models import cnn
 
 from bench import flops
+from bench.data import make_deployment
 
-__all__ = ["System"]
+__all__ = ["TINY_SIZES", "make_deployment", "accuracy", "System"]
+
+# every width and count shrunk; the protocol keys are left as they are
+TINY_SIZES = {"num_clients": 12, "clients_per_round": 3, "samples_per_client": 20,
+              "num_classes": 4, "channels": [4, 8], "fc1_dim": 16, "test_samples": 64,
+              "max_rounds": 20, "target_accuracy": 0.4}
+
+
+def accuracy(params, test_xs, test_ys):
+    """One federation's held-out accuracy, as the program reads it."""
+    return cnn.accuracy(params, test_xs, test_ys)
+
 
 class System:
     """One configuration's federation under one selection strategy."""
@@ -71,9 +86,9 @@ class System:
         over the batch's params read chance for most federations whose
         weights matched the reference (PERF.md, findings of PR 13)."""
         if lockstep == 1:
-            return cnn.accuracy(params, test_xs, test_ys)
+            return accuracy(params, test_xs, test_ys)
         return jnp.stack([
-            cnn.accuracy(jax.tree_util.tree_map(lambda x, s=s: x[s], params), test_xs, test_ys)
+            accuracy(jax.tree_util.tree_map(lambda x, s=s: x[s], params), test_xs, test_ys)
             for s in range(lockstep)])
 
     # ---------------------------------------------------- counted from shapes

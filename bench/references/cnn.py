@@ -3,19 +3,19 @@
 Written from the paper's description (§3-§4) in ``jax.numpy``: the
 2-conv/2-FC CNN (5x5 'SAME' convolutions, ReLU, 2x2 max-pools, FC-1, FC-2),
 its cross-entropy loss, a client's E full-batch gradient-descent steps,
-eq.-(6) aggregation weighted by the clients' sample counts, accuracy, the
-eq.-(11) FC-1 profiles and the eq.-(14) kernel L = SᵀS.  The initial
-weights follow the same recipe as the program's (Kaiming-uniform on
-fan-in, zero biases, one key split four ways), so that the reference
-starts where the program starts without taking the program's weights.
+eq.-(6) aggregation weighted by the clients' sample counts, accuracy and
+the eq.-(11) FC-1 profiles (the eq.-(14) kernel is ``bench/eq14.py``).
+The initial weights follow the same recipe as the program's
+(Kaiming-uniform on fan-in, zero biases, one key split four ways), so that
+the reference starts where the program starts without taking the
+program's weights.
 
-Every product runs at the precision the configuration states
+Every function reads its sizes and settings from the configuration
+``cfg``.  Every product runs at the precision it states
 (``matmul_precision``: on the chip, ``"default"`` is one bfloat16 pass of
-the MXU with float32 accumulation), on float32 arrays; the kernel is
-worked out in float64 on the host.  ``dtype=bfloat16`` gives the control:
-the same arithmetic on bfloat16 arrays, and the kernel in float32 at
-``Precision.HIGH`` on the device.  Every pass runs in blocks, so the
-reference fits beside what the run keeps.
+the MXU with float32 accumulation), on float32 arrays.  ``dtype=bfloat16``
+gives the control: the same arithmetic on bfloat16 arrays.  Every pass
+runs in blocks, so the reference fits beside what the run keeps.
 """
 
 from __future__ import annotations
@@ -24,10 +24,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
-__all__ = ["init_params", "profiles", "eq14_kernel", "replay", "accuracy"]
+__all__ = ["init_params", "profiles", "replay", "accuracy"]
 
 F32 = jnp.float32
 
@@ -80,48 +79,32 @@ def _loss(params, x, y, dtype, precision):
     return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
-def profiles(params, client_xs, dtype=F32, precision="default", block=50):
+def profiles(params, client_xs, cfg: dict, dtype=F32):
     """(C, F) eq.-(11) profiles: each client's mean FC-1 pre-activation."""
+    return _profiles(params, client_xs, dtype=dtype, precision=cfg["matmul_precision"])
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
+def _profiles(params, client_xs, dtype, precision, block=50):
     def one(x):
         return _forward(params, x, dtype, precision)[1].astype(F32).mean(0).astype(dtype)
 
     return lax.map(one, client_xs, batch_size=block)
 
 
-def eq14_kernel(f, dtype=F32) -> np.ndarray:
-    """Eq. (14) and L = SᵀS.  float32 profiles: in float64 on the host;
-    the control's: in float32 at ``Precision.HIGH`` on the device."""
-    if dtype == F32:
-        f = np.asarray(f, np.float64)
-        f = f - f.mean(0)  # distances are translation-invariant
-        sq = (f * f).sum(1)
-        d2 = np.maximum(sq[:, None] + sq[None, :] - 2.0 * (f @ f.T), 0.0)
-        np.fill_diagonal(d2, 0.0)
-        s0 = np.sqrt(d2)
-        s = 1.0 - (s0 - s0.min()) / (s0.max() - s0.min())
-        return s.T @ s
-    return np.asarray(_eq14_high(jnp.asarray(f, F32)), np.float64)
-
-
-@jax.jit
-def _eq14_high(f):
-    hi = lax.Precision.HIGH
-    f = f - f.mean(0)
-    sq = (f * f).sum(1)
-    d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * jnp.dot(f, f.T, precision=hi), 0.0)
-    s0 = jnp.sqrt(d2 * (1.0 - jnp.eye(f.shape[0], dtype=F32)))
-    s = 1.0 - (s0 - s0.min()) / (s0.max() - s0.min())
-    return jnp.dot(s.T, s, precision=hi)
+def replay(params0, client_xs, client_ys, cohorts, cfg: dict, dtype=F32, keep=1.0):
+    """Rounds with the given cohorts (R, k): each client takes
+    ``local_epochs`` full-batch GD steps at ``lr`` from the global params on
+    the first ``keep`` share of its samples, then the eq.-(6) mean weighted
+    by sample counts.
+    -> (params after the R rounds, per-round mean local loss (R,))."""
+    return _replay(params0, client_xs, client_ys, cohorts, lr=float(cfg["lr"]),
+                   steps=int(cfg["local_epochs"]), dtype=dtype,
+                   precision=cfg["matmul_precision"], keep=keep)
 
 
 @functools.partial(jax.jit, static_argnames=("lr", "steps", "dtype", "precision", "keep"))
-def replay(params0, client_xs, client_ys, cohorts, lr, steps, dtype=F32, precision="default",
-           keep=1.0):
-    """Rounds with the given cohorts (R, k): each client takes ``steps``
-    full-batch GD steps from the global params on the first ``keep`` share
-    of its samples, then the eq.-(6) mean weighted by sample counts.
-    -> (params after the R rounds, per-round mean local loss (R,))."""
+def _replay(params0, client_xs, client_ys, cohorts, lr, steps, dtype, precision, keep):
     grad = jax.value_and_grad(lambda p, x, y: _loss(p, x, y, dtype, precision))
 
     def client(p, x, y):
@@ -149,9 +132,13 @@ def replay(params0, client_xs, client_ys, cohorts, lr, steps, dtype=F32, precisi
     return lax.scan(one_round, p, cohorts)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
-def accuracy(params, xs, ys, dtype=F32, precision="default", block=4096):
+def accuracy(params, xs, ys, cfg: dict, dtype=F32):
     """Share of ``xs`` whose largest logit is the label."""
+    return _accuracy(params, xs, ys, dtype=dtype, precision=cfg["matmul_precision"])
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "precision", "block"))
+def _accuracy(params, xs, ys, dtype, precision, block=4096):
     n = xs.shape[0]
     pad = (-n) % block
     xb = jnp.pad(xs, ((0, pad),) + ((0, 0),) * (xs.ndim - 1)).reshape(-1, block, *xs.shape[1:])

@@ -5,7 +5,8 @@
 1. Finds an accelerator with as many chips as the cell asks for, or exits
    non-zero and prints no result.  There is no CPU fallback.
 2. Builds the cell from its files (``bench/configs``, ``bench/workloads``),
-   makes the client data on the device from ``--seed``, and warms up the
+   has the configuration's adapter (``bench/models``) make the client data
+   on the device from ``--seed``, and warms up the
    cell's programs through JAX's persistent compilation cache, kept in
    ``.jax_cache`` inside the checkout.  All of that is ``setup_s``.
 3. Runs federations back to back for ``--seconds`` (whole federations: the
@@ -105,7 +106,6 @@ def main(argv=None) -> None:
 
     use_compile_cache()
     from bench import flops
-    from bench.data import make_deployment
     from bench.federations import Runner, derive_key
 
     cfg = cell.cfg
@@ -113,7 +113,7 @@ def main(argv=None) -> None:
     counter = CompileCounter()
     model = harness.load_module("models", cfg["model"], cell.root)
     system = model.System(cfg, cell.traffic["strategy"])
-    data = make_deployment(cfg, jax.random.key(derive_key(args.seed, 0)))
+    data = model.make_deployment(cfg, jax.random.key(derive_key(args.seed, 0)))
     runner = Runner(system, cfg, cell.traffic, data, args.seed)
     runner.warm_up()
     setup_s = time.perf_counter() - T_START

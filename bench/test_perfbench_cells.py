@@ -1,6 +1,7 @@
 """Every cell's pieces end to end on the CPU at a tiny size (Pallas in
 interpret mode), the command's refusal without a TPU, and a cell, a
-configuration and a metric added as new files only."""
+configuration and a metric added as new files only: a sibling of the CNN,
+and a token federation whose model is no CNN (``bench/fixtures/bigram``)."""
 
 from __future__ import annotations
 
@@ -16,7 +17,6 @@ import jax
 import pytest
 
 from bench import check, harness
-from bench.data import make_deployment
 from bench.federations import Runner, derive_key
 from bench.tiny import tiny
 
@@ -27,9 +27,9 @@ PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 def _run_window(cell, seed, seconds=0.5, trace_dir=None):
     cfg = cell.cfg
-    system = harness.load_module("models", cfg["model"], cell.root).System(
-        cfg, cell.traffic["strategy"])
-    data = make_deployment(cfg, jax.random.key(derive_key(seed, 0)))
+    model = harness.load_module("models", cfg["model"], cell.root)
+    system = model.System(cfg, cell.traffic["strategy"])
+    data = model.make_deployment(cfg, jax.random.key(derive_key(seed, 0)))
     runner = Runner(system, cfg, cell.traffic, data, seed)
     runner.warm_up()
     if trace_dir:
@@ -153,3 +153,77 @@ def test_a_new_cell_config_and_metric_need_only_new_files(tmp_path):
     metrics = harness.read_metrics(cell, ctx)
     assert metrics["chunks_per_federation"]["unit"] == "chunks"
     assert metrics["chunks_per_federation"]["value"] >= 1
+
+
+FIXTURE = ROOT / "bench" / "fixtures" / "bigram"
+
+
+def _bigram_round_flops(cfg, eval_round):
+    """The bigram's round counted by hand: three products of the head per
+    position and local step, one per position for the loss refresh and the
+    held-out forward."""
+    fwd = 2 * (cfg["seq_len"] - 1) * cfg["embed_dim"] * cfg["vocab_size"]
+    kn = cfg["clients_per_round"] * cfg["samples_per_client"]
+    return (kn * cfg["local_epochs"] * 3 * fwd + kn * fwd
+            + (cfg["test_samples"] * fwd if eval_round else 0))
+
+
+def test_a_token_federation_needs_only_new_files(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digest(tmp_path)
+    added = [p.relative_to(FIXTURE) for p in sorted(FIXTURE.rglob("*"))
+             if p.suffix in (".py", ".json")]
+    for rel in added:
+        dest = tmp_path / "bench" / rel
+        assert not dest.exists(), rel
+        shutil.copy(FIXTURE / rel, dest)
+    spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "bigram", "source": "https://arxiv.org/abs/2303.17358",
+                            "file": "bench/configs/bigram.json", "reduced": [],
+                            "why": "a test"})
+    spec["workloads"].append({"name": "bigram.topics", "config": "bigram",
+                              "traffic": "topics", "chips": 1, "why": "a test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    after = _digest(tmp_path)
+    assert {k: v for k, v in after.items() if k != "BENCHMARK.json"} == {
+        k: v for k, v in before.items() if k != "BENCHMARK.json"}
+
+    cell = tiny(harness.load_cell("bigram.topics", root=tmp_path))
+    cfg = cell.cfg
+    assert cfg["num_clients"] == 12 and cfg["vocab_size"] == 32 and cfg["embed_dim"] == 16
+    assert "channels" not in cfg and cell.traffic["lockstep"] == 2
+    seed = 2**33 + 41
+    system, runner, stats = _run_window(cell, seed, seconds=0.3, trace_dir=tmp_path / "t")
+    assert stats["attempted"] >= 2 and stats["fed_rounds"] >= 10
+    from bench import trace
+
+    ctx = harness.MetricContext(trace.load(str(tmp_path / "t")), stats, runner.records, cfg,
+                                cell.traffic, system, PEAK)
+    metrics = harness.read_metrics(cell, ctx)
+    every = cfg["eval_every"]
+    want = sum((r.rounds_run - r.rounds_run // every) * _bigram_round_flops(cfg, False)
+               + r.rounds_run // every * _bigram_round_flops(cfg, True) for r in runner.records)
+    assert metrics["round_mfu"]["value"] == pytest.approx(
+        100 * want / (ctx.window_s * PEAK["bf16_flops_per_s"]), rel=1e-12)
+    listed = {m["name"] for m in spec["per_layer"] if "workloads" in m}
+    assert "eq14_roofline" in listed and not listed & set(metrics)
+    assert {"round_ms", "fed_mfu", "selection_ms", "device_idle_share"} <= set(metrics)
+
+    correct, checks, each = harness.check_window(runner, cell, seed)
+    assert correct, checks
+    assert len(each) == 2
+
+    # the reference on half of each client's batch, put in the program's place
+    ref = harness.load_module("references", cfg["reference"], tmp_path)
+    numbers = {}
+    for batch, slot in harness.pick(runner, cell, seed):
+        snap = runner.snapshot(batch, slot)
+        good = harness.reference_run(ref, cfg, runner, batch, slot, snap)
+        half = harness.reference_run(ref, cfg, runner, batch, slot, snap, keep=0.5)
+        for k, v in check.compare(half, good).items():
+            numbers[k] = max(numbers.get(k, 0.0), v)
+    correct, checks = check.verdict(numbers, cell.workload["limits"])
+    assert not correct
+    assert checks["update_gap"]["value"] > checks["update_gap"]["limit"], checks

@@ -1,13 +1,12 @@
 """The comparison that decides ``correct`` fails a run whose program is
 broken underneath it, on the CPU at a tiny size, with each cell's own
 limits: a round that returns its weights unchanged, half of each client's
-batch left out (the mean taken over the rest), and a cohort trained other
-than the one reported.  The harness's look for a chip is skipped;
-everything else is the run's own path (warm-up, window, check).
-
-Not here yet (PERF.md, section 7): the control, the plain reference in
-bfloat16 put in the program's place, and an accuracy altered where it is
-produced; they need limits set from readings on the chip.
+batch left out (the mean taken over the rest), a cohort trained other
+than the one reported, and the held-out accuracy read against labels
+shifted by one sample (an answer altered where it is produced).  The
+harness's look for a chip is skipped; everything else is the run's own
+path (warm-up, window, check).  The control, the plain reference in
+bfloat16 put in the program's place, fails the same limits.
 """
 
 from __future__ import annotations
@@ -17,10 +16,10 @@ import json
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
-from bench import harness
-from bench.data import make_deployment
+from bench import check, harness
 from bench.federations import Runner, derive_key
 from bench.tiny import tiny
 from repro.fl import engine
@@ -32,13 +31,13 @@ SEED = 2**32 + 11
 
 def _runner(cell, seed=SEED):
     cfg = cell.cfg
-    system = harness.load_module("models", cfg["model"], cell.root).System(
-        cfg, cell.traffic["strategy"])
-    data = make_deployment(cfg, jax.random.key(derive_key(seed, 0)))
+    model = harness.load_module("models", cfg["model"], cell.root)
+    system = model.System(cfg, cell.traffic["strategy"])
+    data = model.make_deployment(cfg, jax.random.key(derive_key(seed, 0)))
     return Runner(system, cfg, cell.traffic, data, seed)
 
 
-def _unchanged(monkeypatch):
+def _unchanged(monkeypatch, runner):
     for fn in ("run_scanned", "run_many"):
         orig = getattr(engine, fn)
 
@@ -49,7 +48,7 @@ def _unchanged(monkeypatch):
         monkeypatch.setattr(engine, fn, broken)
 
 
-def _half_batch(monkeypatch):
+def _half_batch(monkeypatch, runner):
     orig = engine.make_client_batches
 
     def broken(cfg, key, xs, ys, sel):
@@ -60,7 +59,7 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(engine, "make_client_batches", broken)
 
 
-def _cohort_altered(monkeypatch):
+def _cohort_altered(monkeypatch, runner):
     orig = engine.make_client_batches
 
     def broken(cfg, key, xs, ys, sel):
@@ -69,18 +68,45 @@ def _cohort_altered(monkeypatch):
     monkeypatch.setattr(engine, "make_client_batches", broken)
 
 
+def _answer_altered(monkeypatch, runner):
+    read = runner.system.accuracy
+
+    def broken(params, xs, ys, lockstep):
+        return read(params, xs, jnp.roll(ys, 1), lockstep)
+
+    monkeypatch.setattr(runner.system, "accuracy", broken)
+
+
 FAULTS = {"state_unchanged": _unchanged, "half_batch": _half_batch,
-          "cohort_altered": _cohort_altered}
+          "cohort_altered": _cohort_altered, "answer_altered": _answer_altered}
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 @pytest.mark.parametrize("name", CELLS)
 def test_broken_program_is_not_correct(name, fault, monkeypatch):
-    FAULTS[fault](monkeypatch)
     cell = tiny(harness.load_cell(name), max_rounds=5)
     runner = _runner(cell)
+    FAULTS[fault](monkeypatch, runner)
     runner.warm_up()
     runner.window(1e-3)
     correct, checks, _ = harness.check_window(runner, cell, SEED)
     assert not correct, checks
 
+
+@pytest.mark.parametrize("name", CELLS)
+def test_control_is_not_correct(name):
+    cell = tiny(harness.load_cell(name), max_rounds=5)
+    runner = _runner(cell)
+    runner.run_batch(0)
+    ref = harness.load_module("references", cell.cfg["reference"], cell.root)
+    numbers = {}
+    for batch, slot in harness.pick(runner, cell, SEED):
+        snap = runner.snapshot(batch, slot)
+        want = harness.reference_run(ref, cell.cfg, runner, batch, slot, snap)
+        ctrl = harness.reference_run(ref, cell.cfg, runner, batch, slot, snap,
+                                     dtype=jnp.bfloat16)
+        for k, v in check.compare(ctrl, want).items():
+            numbers[k] = max(numbers.get(k, 0.0), v)
+    assert numbers
+    correct, checks = check.verdict(numbers, cell.workload["limits"])
+    assert not correct, checks

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import pytest
 
 from bench import harness, scopes, trace
-from bench.data import make_deployment
 from bench.federations import Runner, derive_key
 from bench.tiny import tiny
 
@@ -28,10 +27,11 @@ def window(tmp_path_factory):
     d = tmp_path_factory.mktemp("scopes")
     cell = tiny(harness.load_cell("paper-cnn.table1"))
     cfg = cell.cfg
-    system = harness.load_module("models", cfg["model"]).System(cfg, cell.traffic["strategy"])
+    model = harness.load_module("models", cfg["model"])
+    system = model.System(cfg, cell.traffic["strategy"])
     seed = 2**33 + 5
     runner = Runner(system, cfg, cell.traffic,
-                    make_deployment(cfg, jax.random.key(derive_key(seed, 0))), seed)
+                    model.make_deployment(cfg, jax.random.key(derive_key(seed, 0))), seed)
     runner.warm_up()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

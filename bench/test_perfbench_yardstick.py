@@ -30,7 +30,8 @@ def test_cnn_counts_match_hand_counts():
     plain = 2 * 7 * 3 * flops.cnn_train_flops(TINY) + 2 * 7 * fwd
     assert flops.round_flops(TINY, False) == plain
     assert flops.round_flops(TINY, True) == plain + 11 * fwd
-    assert flops.rounds_flops(TINY, 12) == 10 * plain + 2 * (plain + 11 * fwd)
+    per_round = lambda e: flops.round_flops(TINY, e)  # noqa: E731
+    assert flops.rounds_flops(per_round, 5, 12) == 10 * plain + 2 * (plain + 11 * fwd)
 
 
 def test_paper_cnn_round_is_about_a_quarter_teraflop():

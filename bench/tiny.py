@@ -1,19 +1,19 @@
-"""A cell cut to a size the CPU tests can run in seconds: every width and
-count shrunk, the protocol (strategy, lockstep, chunking, stopping rule,
-check) unchanged.  Only the tests use it; no chip run does."""
+"""A cell cut to a size the CPU tests can run in seconds: the sizes its
+configuration's adapter gives in ``TINY_SIZES`` in place of the
+configuration's own, the protocol (strategy, lockstep, chunking, stopping
+rule, check) unchanged.  Only the tests use it; no chip run does."""
 
 from __future__ import annotations
 
 import dataclasses
 
-TINY_SIZES = {"num_clients": 12, "clients_per_round": 3, "samples_per_client": 20,
-              "num_classes": 4, "channels": [4, 8], "fc1_dim": 16, "test_samples": 64,
-              "max_rounds": 20, "target_accuracy": 0.4}
+from bench import harness
 
 
 def tiny(cell, **extra):
-    """The cell with its configuration cut to ``TINY_SIZES`` and at most two
-    federations in lockstep."""
+    """The cell with its configuration cut to its adapter's ``TINY_SIZES``
+    and at most two federations in lockstep."""
+    sizes = harness.load_module("models", cell.cfg["model"], cell.root).TINY_SIZES
     traffic = dict(cell.traffic, lockstep=min(2, int(cell.traffic["lockstep"])))
-    return dataclasses.replace(cell, cfg={**cell.cfg, **TINY_SIZES, **extra},
+    return dataclasses.replace(cell, cfg={**cell.cfg, **sizes, **extra},
                                workload={**cell.workload, "traffic": traffic})
