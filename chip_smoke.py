@@ -49,6 +49,9 @@ import numpy as np  # noqa: E402
 C, C_RAGGED, F = 100, 1000, 128
 B, S, H, HK, HD = 8, 2048, 15, 5, 64
 RWKV_T, RWKV_H = 512, 64
+# the paper CNN's first stage in the benchmark: 8 lockstep federations, 600
+# samples of 28x28, 16 channels, 5x5 kernel
+LOCKSTEP, SAMPLES, HW, CONV1 = 8, 600, 28, 16
 ROUNDS = 10  # federation rounds, one and four chips
 MESH_CHIPS, COHORT_CAP = 4, 10
 # smollm-360m serving: requests, slots, prompt length, largest budget
@@ -72,6 +75,9 @@ KERNEL_TOLS = {
     "wkv6.y": 1e-2,
     # f32 state: 512 steps of f32 arithmetic, only the summation order differs
     "wkv6.state": 1e-4,
+    # the same routing, and bf16 products exact in f32 in both: only the order
+    # of the f32 sums (470,400 terms per weight) differs
+    "conv_pool_bwd": 1e-5,
 }
 # Pallas vs jnp eq.-(14) kernel of the federation's own profiles, both f32.
 # Clients' FC-1 means lie close together (squared norms ~1e3, smallest
@@ -146,6 +152,8 @@ def _report_kernel(name, got, want, tol_key=None):
 
 def kernel_phase(seed: int) -> None:
     """Each kernel once at the widths above."""
+    from repro.kernels.conv_pool_bwd import ops as conv_pool_bwd_ops
+    from repro.kernels.conv_pool_bwd import ref as conv_pool_bwd_ref
     from repro.kernels.flash_attention import ref as flash_ref
     from repro.kernels.flash_attention.decode import flash_decode_kernel
     from repro.kernels.flash_attention.flash_attention import flash_attention_kernel
@@ -212,6 +220,19 @@ def kernel_phase(seed: int) -> None:
     name = f"wkv6[T={RWKV_T},H={RWKV_H}]"
     _report_kernel(name + ".y", y, y_ref, "wkv6.y")
     _report_kernel(name + ".state", s_out, s_ref, "wkv6.state")
+
+    # a conv output of few values and a positive bias: windows of equal
+    # maxima, which only the first may take the gradient of
+    y = jnp.round(2.0 * normal((LOCKSTEP, SAMPLES, HW, HW, CONV1))) / 2.0
+    b = jnp.abs(0.3 * normal((LOCKSTEP, CONV1))) + 0.5
+    x = normal((LOCKSTEP, SAMPLES, HW, HW, 1))
+    g = normal((LOCKSTEP, SAMPLES, HW // 2, HW // 2, CONV1))
+    got = jax.vmap(lambda *a: conv_pool_bwd_ops.conv_pool_bwd(*a, (5, 5)))(y, b, x, g)
+    with exact:
+        want = jax.vmap(lambda *a: conv_pool_bwd_ref.conv_pool_bwd_ref(*a, (5, 5)))(y, b, x, g)
+    name = f"conv_pool_bwd[S={LOCKSTEP},N={SAMPLES},C={CONV1}]"
+    _report_kernel(name + ".dw", got[0], want[0], "conv_pool_bwd")
+    _report_kernel(name + ".db", got[1], want[1], "conv_pool_bwd")
 
 
 # -------------------------------------------------------------- federation

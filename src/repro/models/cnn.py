@@ -5,6 +5,10 @@ Pure-JAX (init/apply pairs).  ``apply_with_features`` exposes the FC-1
 profiling (eq. 11).  Four parameter-initialisation schemes are provided for
 the Fig. 4-6 robustness experiments: kaiming_{uniform,normal} and
 xavier_{uniform,normal}.
+
+On a TPU the first stage's backward (the pool's gradient routing, the ReLU
+mask, the bias and weight gradients) is one Pallas pass
+(``kernels/conv_pool_bwd``); its forward stays the XLA ops of every stage.
 """
 
 from __future__ import annotations
@@ -16,7 +20,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.conv_pool_bwd import ops as conv_pool_bwd_ops
+
 __all__ = ["init_cnn", "apply_cnn", "apply_with_features", "cnn_loss", "accuracy", "INIT_SCHEMES"]
+
+# the named scope of the first stage's backward kernel on its op-name path
+BWD_SCOPE = "conv1_bwd"
 
 
 def _fan_in_out(shape):
@@ -77,12 +86,11 @@ def init_cnn(
     }
 
 
-def _conv(x, p):
-    y = lax.conv_general_dilated(
-        x, p["w"], window_strides=(1, 1), padding="SAME",
+def _conv(x, w):
+    return lax.conv_general_dilated(
+        x, w, window_strides=(1, 1), padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
-    return y + p["b"]
 
 
 def _maxpool2(x):
@@ -91,16 +99,70 @@ def _maxpool2(x):
     )
 
 
+def _stage(x, w, b):
+    """conv + bias → ReLU → 2×2/2 max-pool."""
+    return _maxpool2(jax.nn.relu(_conv(x, w) + b))
+
+
+def _pallas_backward() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _bf16_products() -> bool:
+    """Whether the default matmul precision is one bf16 pass, the products
+    the kernel forms; a higher one keeps XLA's backward."""
+    return jax.config.jax_default_matmul_precision in (None, "default", "fastest", "bfloat16")
+
+
+@jax.custom_vjp
+def _stage_kernel_bwd(x, w, b):
+    """``_stage`` whose backward is the Pallas kernel (for an x that takes
+    no gradient)."""
+    return _stage(x, w, b)
+
+
+def _stage_kernel_bwd_fwd(x, w, b):
+    if x.perturbed:  # an input that takes a gradient: XLA's backward
+        out, vjp = jax.vjp(_stage, x.value, w.value, b.value)
+        return out, (vjp,)
+    y = _conv(x.value, w.value)
+    return _maxpool2(jax.nn.relu(y + b.value)), (y, w.value, b.value, x.value)
+
+
+def _stage_kernel_bwd_bwd(res, g):
+    if len(res) == 1:
+        return res[0](g)
+    y, w, b, x = res
+    # not an ``fl.*`` stage: the kernel stays in its caller's stage
+    # (``fl.local_update`` in the federation engine)
+    with jax.named_scope(BWD_SCOPE):
+        dw, db = conv_pool_bwd_ops.conv_pool_bwd(y, b, x, g, w.shape[:2])
+    return None, dw, db
+
+
+_stage_kernel_bwd.defvjp(_stage_kernel_bwd_fwd, _stage_kernel_bwd_bwd, symbolic_zeros=True)
+
+
+def _first_stage(x, p):
+    """The first stage; its backward is the Pallas kernel on a TPU where the
+    kernel covers the shapes (one input channel, even H and W, channels in
+    whole sublane tiles, float32) and the precision (bf16 products)."""
+    _, h, w, cin = x.shape
+    c = p["w"].shape[-1]
+    if (_pallas_backward() and _bf16_products() and cin == 1 and h % 2 == 0 and w % 2 == 0
+            and c % 8 == 0 and x.dtype == p["w"].dtype == p["b"].dtype == jnp.float32):
+        return _stage_kernel_bwd(x, p["w"], p["b"])
+    return _stage(x, p["w"], p["b"])
+
+
 def apply_with_features(params: Dict, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Forward pass returning (logits, FC-1 pre-activations).
 
     The FC-1 pre-activation is the Theorem-1 variable whose per-neuron mean
     over the local dataset forms the client's data profile f_c (eq. 11).
     """
-    h = jax.nn.relu(_conv(x, params["conv1"]))
-    h = _maxpool2(h)
-    h = jax.nn.relu(_conv(h, params["conv2"]))
-    h = _maxpool2(h)
+    h = _first_stage(x, params["conv1"])
+    h = _stage(h, params["conv2"]["w"], params["conv2"]["b"])
     h = h.reshape(h.shape[0], -1)
     fc1_pre = h @ params["fc1"]["w"] + params["fc1"]["b"]
     h = jax.nn.relu(fc1_pre)
